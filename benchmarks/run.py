@@ -38,6 +38,9 @@ def main() -> None:
                          "({bench, shape, dtype, backend, ms, gbps}) to PATH")
     args = ap.parse_args()
 
+    from repro.launch.env import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (  # noqa: PLC0415
         bench_e2e_overhead,
         bench_fused_quant,

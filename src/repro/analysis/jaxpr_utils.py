@@ -9,13 +9,13 @@ rules literally share an implementation.
 
 All walkers recurse through ``eqn.params.values()`` (``ClosedJaxpr`` /
 ``Jaxpr`` / list / tuple), which covers cond branches, scan/while
-bodies, pjit calls and remat -- anywhere jax 0.4.x stashes a subjaxpr.
+bodies, pjit calls and remat -- anywhere jax stashes a subjaxpr.
 """
 from __future__ import annotations
 
 from typing import Iterator, List, Tuple
 
-from jax.core import ClosedJaxpr, Jaxpr
+from repro.jaxapi import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "as_jaxpr",
@@ -77,7 +77,7 @@ def pallas_call_eqns(jaxpr) -> List:
 
 def kernel_jaxprs(jaxpr) -> List[Jaxpr]:
     """The kernel-body jaxprs of every ``pallas_call`` in ``jaxpr``
-    (``params["jaxpr"]`` is a raw ``Jaxpr`` in jax 0.4.x)."""
+    (``params["jaxpr"]`` is a raw ``Jaxpr``)."""
     return [e.params["jaxpr"] for e in pallas_call_eqns(jaxpr)]
 
 

@@ -82,6 +82,7 @@ def _launch(kernel, schedule: str, *, n=256, d=640, m=8, bn=128):
     from jax.experimental.pallas import tpu as pltpu
 
     from repro.core.api import QuantEpilogue, plan_for
+    from repro.jaxapi import ANY
     from repro.kernels.quant_dot import _scratch_dtype, quant_dot_blocks
     from repro.kernels.registry import _plan_mats
 
@@ -103,8 +104,8 @@ def _launch(kernel, schedule: str, *, n=256, d=640, m=8, bn=128):
                     pltpu.VMEM((2, 1, bn), jnp.float32),
                     pltpu.SemaphoreType.DMA((2,)),
                     pltpu.SemaphoreType.DMA((2,))]
-        wq_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        sw_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        wq_spec = pl.BlockSpec(memory_space=ANY)
+        sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
         body = functools.partial(kernel, **common)
 

@@ -6,9 +6,9 @@ knobs, callers build (or let us cache) a :class:`HadamardPlan` --
 everything shape-dependent is precomputed exactly once per
 ``(n, dtype, compute_dtype, backend, epilogue, scale, block_m)`` key:
 
-  * the 128-factorization ``n = 128^k * r`` and the stacked per-pass base
-    matrices (including the I (x) H_r diagonal tiling for r > 1 and the
-    scale folded into pass 0);
+  * the pass matrices of ``H_n = H_a (x) H_b`` (b = min(n, 128), one
+    lane pass and one sublane pass; ``hadamard.base_matrices_np``) with
+    the scale folded into the first;
   * the resolved backend (registry lookup: explicit > env override >
     auto-by-size/platform);
   * the VMEM row-tile ``block_m``.
@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Optional, Tuple, Union
 
 import jax
@@ -53,11 +54,12 @@ from jax.dtypes import float0
 
 from repro.core.hadamard import (
     base_matrices_np,
-    factorize,
     largest_pow2_divisor,
+    pack_pass_mats,
     resolve_compute_dtype,
     resolve_scale,
 )
+from repro.jaxapi import fp8_operand_dtype, interpret_mode, shard_map
 from repro.kernels import registry
 from repro.kernels.ref import is_pow2
 from repro.kernels.registry import QSPECS, get_backend, select_backend
@@ -119,8 +121,6 @@ class HadamardPlan:
     scale: Optional[float]           # numeric scale folded into pass 0 (None = +-1)
     epilogue: Optional[QuantEpilogue]
     block_m: Optional[int]           # VMEM row tile (None = per-call heuristic)
-    k: int                           # number of 128-factors of p
-    r: int                           # residual pow2 factor (1 <= r < 128)
     mesh_axes: Optional[Tuple[str, ...]] = None
                                      # mesh axes the quant_dot weight's
                                      # out-channel dim is sharded over --
@@ -142,14 +142,13 @@ class HadamardPlan:
 def _build_plan(n, p, dtype_name, compute_dtype, scale_val, backend, epilogue,
                 block_m, mesh_axes=None):
     if p == 1:
-        k, r, mats = 0, 1, np.ones((1, 1, 1), np.float32)
+        mats = np.ones((1, 1, 1), np.float32)
     else:
-        k, r = factorize(p)
-        mats = np.stack(base_matrices_np(p, scale_val))
+        mats = pack_pass_mats(base_matrices_np(p, scale_val))
     return HadamardPlan(
         n=n, p=p, dtype=dtype_name, compute_dtype=compute_dtype,
         backend=backend, scale=scale_val, epilogue=epilogue, block_m=block_m,
-        k=k, r=r, mesh_axes=mesh_axes, mats=mats,
+        mesh_axes=mesh_axes, mats=mats,
     )
 
 
@@ -214,13 +213,78 @@ def _group(x: jnp.ndarray, plan: HadamardPlan) -> jnp.ndarray:
     return x.reshape(*x.shape[:-1], plan.n // plan.p, plan.p)
 
 
+def _row_axes(mesh, m: int, exclude=()) -> Tuple[str, ...]:
+    """Mesh axes to split m rows over: every axis not in ``exclude``
+    whose running size divides m, in mesh order (size-1 axes are kept --
+    the spec stays row-sharded and costs nothing)."""
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    axes, total = [], 1
+    for a in mesh.axis_names:
+        if a not in exclude and m % (total * sizes[a]) == 0:
+            axes.append(a)
+            total *= sizes[a]
+    return tuple(axes)
+
+
+def _on_rows(be, fn, x, n: int, *operands, cols=None):
+    """Run ``fn(rows, *operands)``, a kernel of backend ``be`` over the
+    rows of ``x`` (its leading dims), under the active sharding-rules
+    mesh -- the one place that decides how a kernel call splits over a
+    mesh. Pallas (Mosaic) kernels cannot be partitioned by GSPMD, so with
+    more than one device ``shard_map`` splits the flattened rows over the
+    mesh axes ``_row_axes`` picks. ``cols`` names the mesh axes that
+    split the last axis of every operand and of the output (a weight's
+    out-channel shards), and the rows then split over the other axes.
+    Without ``cols`` every device gets the operands whole, which is
+    counted as ``_sharded_fallback("replicated_operand")``: a weight the
+    mesh holds sharded is gathered on every call. Every output keeps the
+    row axis first. Without ``cols``, backends that partition natively,
+    and single-device runs, call ``fn`` directly."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import current_mesh, sharding_rules
+
+    mesh = current_mesh()
+    if cols is None and (not be.mosaic or mesh is None
+                         or mesh.devices.size == 1):
+        return fn(x, *operands)
+    lead = x.shape[:-1]
+    x2 = x.reshape(-1, n)
+    rows = _row_axes(mesh, x2.shape[0], cols or ()) or None
+    if cols:
+        c = cols if len(cols) > 1 else cols[0]
+        in_specs = (P(rows, None),) + (P(None, c),) * len(operands)
+        out_specs = P(rows, c)
+    else:
+        if operands:
+            _sharded_fallback(
+                "replicated_operand",
+                f"a {be.name} kernel over the rows of a "
+                f"{mesh.devices.shape} mesh gets its weight whole on every "
+                "device (no out-channel mesh axes: the plan has none, or "
+                "they do not divide the weight); a weight stored sharded "
+                "is gathered on every call")
+        in_specs = (P(rows, None),) + (P(),) * len(operands)
+        out_specs = P(rows, None)
+
+    def local(*args):
+        # the sharding-rules mesh is cleared inside, so nothing below
+        # re-shards or constrains
+        with sharding_rules(None):
+            return fn(*args)
+
+    out = shard_map(local, mesh=mesh, in_specs=in_specs,
+                    out_specs=out_specs)(x2, *operands)
+    return jax.tree.map(lambda o: o.reshape(*lead, o.shape[-1]), out)
+
+
 def _dispatch_transform(x, plan: HadamardPlan, interpret: bool):
     if plan.p == 1:
         return x if plan.scale is None else x * jnp.asarray(plan.scale, x.dtype)
     be = get_backend(plan.backend)
-    if plan.grouped:
-        return be.transform(_group(x, plan), plan, interpret).reshape(x.shape)
-    return be.transform(x, plan, interpret)
+    xg = _group(x, plan) if plan.grouped else x
+    y = _on_rows(be, lambda r: be.transform(r, plan, interpret), xg, plan.p)
+    return y.reshape(x.shape)
 
 
 def _apply_epilogue_xla(y, epi: QuantEpilogue, out_dtype):
@@ -249,14 +313,18 @@ def _fusable(plan: HadamardPlan) -> bool:
 
 def _dispatch_fused(x, plan: HadamardPlan, interpret: bool):
     if _fusable(plan):
-        return get_backend(plan.backend).fused(x, plan, interpret)
+        be = get_backend(plan.backend)
+        return _on_rows(be, lambda r: be.fused(r, plan, interpret), x,
+                        plan.p)
     y = _dispatch_transform(x, _strip(plan), interpret)
     return _apply_epilogue_xla(y, plan.epilogue, x.dtype)
 
 
 def _dispatch_fused_dequant(x, plan: HadamardPlan, interpret: bool):
     if _fusable(plan):
-        return get_backend(plan.backend).fused_dequant(x, plan, interpret)
+        be = get_backend(plan.backend)
+        return _on_rows(be, lambda r: be.fused_dequant(r, plan, interpret),
+                        x, plan.p)
     y = _dispatch_transform(x, _strip(plan), interpret)
     return _apply_epilogue_xla(y, plan.epilogue, x.dtype)
 
@@ -351,8 +419,9 @@ def hadamard(
     :class:`QuantEpilogue` (the fake-quantized tensor when the epilogue
     has ``dequant=True``).
 
-    ``interpret=None`` auto-selects Pallas interpret mode off-TPU so CPU
-    CI validates the same kernel code path.
+    ``interpret=None`` compiles the Pallas kernels on a TPU and runs them
+    in interpret mode on the CPU (``jaxapi.interpret_mode``), so CPU
+    tests validate the same kernel code path.
     """
     n = x.shape[-1]
     if plan is None:
@@ -385,7 +454,7 @@ def hadamard(
                 "build a plan with plan_for(n, dtype=x.dtype, ...)"
             )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if plan.epilogue is None:
         return _transform(x, plan, interpret)
     if plan.epilogue.dequant:
@@ -398,21 +467,30 @@ def _qd_fusable(plan: HadamardPlan) -> bool:
     """Can the rotate+quantize+dot run as the backend's single kernel?
     Mirrors ``_fusable`` plus the backend must host a ``quant_dot`` and
     the minimal (p, 128) weight tile must fit the kernel's VMEM budget
-    (fp8 operands cost 3 bytes/element in VMEM: storage + the exact bf16
-    embedding; oversize plans take the unfused fallback instead of
-    launching an over-budget kernel)."""
+    (fp8 operands cost ``_FP8_OPERAND_BYTES`` a element in VMEM: storage,
+    the f32 conversion and the exact bf16 embedding). An oversize plan takes the unfused path instead of
+    launching an over-budget kernel -- warned once per process and
+    counted in ``TRACE_COUNTS[("quant_dot", "vmem_unfused")]``."""
     from repro.kernels.quant_dot import _FP8_OPERAND_BYTES
 
     be = get_backend(plan.backend)
     wb = 1 if QSPECS[plan.epilogue.mode][2] else _FP8_OPERAND_BYTES
-    return (
+    kernel_ok = (
         not plan.grouped
         and plan.p > 1
         and plan.epilogue.per_token
         and getattr(be, "quant_dot", None) is not None
         and be.supports(plan.p)
-        and plan.p * 128 * wb <= registry._VMEM_BUDGET_BYTES
     )
+    if kernel_ok and plan.p * 128 * wb > registry._VMEM_BUDGET_BYTES:
+        registry.warn_once(
+            ("quant_dot", "vmem_unfused"),
+            f"the n={plan.p} {plan.epilogue.mode} quant_dot weight tile "
+            "exceeds the kernel's VMEM budget; the site runs unfused "
+            "(warned once per process; TRACE_COUNTS[('quant_dot', "
+            "'vmem_unfused')] keeps counting)")
+        return False
+    return kernel_ok
 
 
 def _resolve_mesh_axes(weight_axes, d: Optional[int]):
@@ -473,39 +551,15 @@ def _strip_mesh(plan: HadamardPlan) -> HadamardPlan:
         plan.backend, plan.epilogue, plan.block_m)
 
 
-def _row_shard_axes(mesh, plan: HadamardPlan, m: int) -> Tuple[str, ...]:
-    """Mesh axes to row-shard the activation over inside the sharded
-    quant_dot: the logical 'batch' (data) axes of the active rules table,
-    minus axes already spent on the weight's out-channel shards, minus
-    axes whose cumulative size does not divide the row count (same guard
-    as ``distributed.sharding._build_parts``). Size-1 axes are kept --
-    the spec stays structurally row-sharded and costs nothing."""
-    from repro.distributed.sharding import _resolve_axis
-
-    ax = _resolve_axis(mesh, "batch")
-    if ax is None:
-        return ()
-    axes = (ax,) if isinstance(ax, str) else tuple(ax)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    keep, total = [], 1
-    for a in axes:
-        if a in plan.mesh_axes:
-            continue
-        if m % (total * sizes[a]) == 0:
-            keep.append(a)
-            total *= sizes[a]
-    return tuple(keep)
-
-
 def _sharded_quant_dot(x, wq, sw, plan: HadamardPlan, interpret: bool,
                        schedule=None):
-    """quant_dot over a mesh via ``shard_map``, fused and data-parallel:
+    """quant_dot over a mesh via ``_on_rows``'s ``shard_map``, fused and
+    data-parallel:
 
-      * the activation is ROW-SHARDED over the mesh data axes (the rules
-        table's 'batch' axes, minus any axis the weight already uses,
-        divisibility-guarded) -- each shard rotates and quantizes only
-        its own rows, so transform work is data-parallel instead of
-        replicated per shard;
+      * the activation is ROW-SHARDED over every mesh axis the weight
+        does not use (divisibility-guarded, ``_row_axes``) -- each shard
+        rotates and quantizes only its own rows, so transform work is
+        data-parallel instead of replicated per shard;
       * the contraction axis is never split (the Hadamard spans it): each
         shard contracts against ITS slice of the weight columns with ITS
         slice of the per-out-channel scales, so per-shard weight scales
@@ -521,30 +575,20 @@ def _sharded_quant_dot(x, wq, sw, plan: HadamardPlan, interpret: bool,
     Returns None when the plan's mesh axes are not provided by the
     current mesh (caller falls back to the replicated single-device path
     and records ``mesh_mismatch``)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
     from repro.distributed.sharding import current_mesh
     from repro.kernels.quant_dot import epilogue_dot
 
     mesh = current_mesh()
     if mesh is None or any(a not in mesh.axis_names for a in plan.mesh_axes):
         return None
-    spec_d = plan.mesh_axes if len(plan.mesh_axes) > 1 else plan.mesh_axes[0]
     local_plan = _strip_mesh(plan)
     epi = plan.epilogue
-    lead, d = x.shape[:-1], wq.shape[-1]
-    x2 = x.reshape(-1, plan.n)
-    sw2 = sw.reshape(1, d).astype(jnp.float32)
-    row_axes = _row_shard_axes(mesh, plan, x2.shape[0])
-    spec_m = row_axes if len(row_axes) > 1 else (
-        row_axes[0] if row_axes else None)
-
+    d = wq.shape[-1]
     be = get_backend(local_plan.backend)
     fused = _qd_fusable(local_plan) and be.quant_dot_fused
     _LAST_SHARDED_DISPATCH.update(
-        fused=fused, row_axes=row_axes, mesh_axes=plan.mesh_axes,
-        backend=local_plan.backend)
+        fused=fused, mesh_axes=plan.mesh_axes, backend=local_plan.backend,
+        row_axes=_row_axes(mesh, math.prod(x.shape[:-1]), plan.mesh_axes))
     if fused:
         def local(xl, wl, sl):
             # the fused kernel, shard-local: xl is this shard's rows,
@@ -570,12 +614,9 @@ def _sharded_quant_dot(x, wq, sw, plan: HadamardPlan, interpret: bool,
             q, s = registry._quantize_rows(y.astype(jnp.float32), epi.mode)
             return epilogue_dot(q, s, wl, sl, epi.mode, jnp.dtype(plan.dtype))
 
-    out = shard_map(
-        local, mesh=mesh,
-        in_specs=(P(spec_m, None), P(None, spec_d), P(None, spec_d)),
-        out_specs=P(spec_m, spec_d), check_rep=False,
-    )(x2, wq, sw2)
-    return out.reshape(*lead, d)
+    return _on_rows(be, local, x, plan.n, wq,
+                    sw.reshape(1, d).astype(jnp.float32),
+                    cols=plan.mesh_axes)
 
 
 def _dispatch_quant_dot(x, wq, sw, plan: HadamardPlan, interpret: bool,
@@ -612,8 +653,11 @@ def _dispatch_quant_dot(x, wq, sw, plan: HadamardPlan, interpret: bool,
             f"per_token={plan.epilogue.per_token}); quant_dot runs the "
             "replicated single-device path")
     if _qd_fusable(plan):
-        return get_backend(plan.backend).quant_dot(x, wq, sw, plan,
-                                                   interpret, schedule)
+        be = get_backend(plan.backend)
+        return _on_rows(
+            be, lambda r, w, sc: be.quant_dot(r, w, sc, plan, interpret,
+                                              schedule),
+            x, plan.n, wq, sw)
     from repro.kernels.quant_dot import epilogue_dot
 
     y = _dispatch_transform(x, _strip(plan), interpret)
@@ -677,8 +721,10 @@ def _abft_quant_dot_impl(x, wq, sw, cw, plan, interpret, schedule):
     registry.TRACE_COUNTS[("abft", "quant_dot_site")] += 1
     be = get_backend(plan.backend)
     if _qd_fusable(plan) and be.quant_dot_fused:
-        y, resid = be.quant_dot(x, wq, sw, plan, interpret, schedule,
-                                check=cw)
+        y, resid = _on_rows(
+            be, lambda r, w, sc, c: be.quant_dot(r, w, sc, plan, interpret,
+                                                 schedule, check=c),
+            x, plan.n, wq, sw, cw)
     else:
         y = _dispatch_quant_dot(x, wq, sw, plan, interpret, schedule)
         resid = xla_quant_dot_resid(x, wq, sw, cw, plan, interpret)
@@ -848,7 +894,7 @@ def quant_dot(
             "QuantEpilogue(mode))"
         )
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if isinstance(w, tuple):
         wq, sw = w
         if wq.shape[0] != n:
@@ -911,8 +957,8 @@ def _experts_einsum_qw(x, wq, sw, plan: HadamardPlan, interpret: bool):
                          preferred_element_type=jnp.int32
                          ).astype(jnp.float32)
     else:
-        acc = jnp.einsum("becf,efd->becd",
-                         q.astype(jnp.bfloat16), wq.astype(jnp.bfloat16),
+        odt = fp8_operand_dtype()
+        acc = jnp.einsum("becf,efd->becd", q.astype(odt), wq.astype(odt),
                          preferred_element_type=jnp.float32)
     out = acc * s * sw[None]                            # (B,E,c,d)*(1,E,1,d)
     return out.astype(x.dtype)
@@ -1054,7 +1100,7 @@ def quant_dot_experts(x, w, plan: HadamardPlan,
     from repro.core.wquant import QTensor
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if isinstance(w, QTensor):
         return _quant_dot_experts_qw(x, w.q, w.scale, plan, interpret,
                                      schedule)
@@ -1291,7 +1337,7 @@ class QuantDotSpec:
             return self._apply_raw(w.dequant(x.dtype), interpret, x)
         if self.rotate:
             if interpret is None:
-                interpret = jax.default_backend() != "tpu"
+                interpret = interpret_mode()
             plan = self.plan(x.dtype, d=w.q.shape[-1])
             if self._abft_verifying(w):
                 if plan.mesh_axes is None:
@@ -1328,7 +1374,7 @@ class QuantDotSpec:
                 x, w, QuantConfig(mode=self.mode, per_token=self.per_token))
         plan = self.plan(x.dtype, d=w.shape[-1])
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
         return _quant_dot_w(x, w, plan, interpret, self.schedule)
 
     # ----------------------------------------------------------- experts
@@ -1357,7 +1403,7 @@ class QuantDotSpec:
         if self.rotate:
             if self._abft_verifying(w):
                 if interpret is None:
-                    interpret = jax.default_backend() != "tpu"
+                    interpret = interpret_mode()
                 plan = self.plan(x.dtype)
                 if _qd_experts_fusable(plan):
                     return _quant_dot_experts_qw_abft(

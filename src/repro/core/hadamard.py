@@ -1,28 +1,26 @@
 """Tensorized Kronecker-factored Walsh-Hadamard transform (pure JAX).
 
 This is the XLA-level embodiment of the paper's idea: instead of log2(n)
-scalar butterfly stages, run ceil(log_128(n)) dense matmul passes against a
-128-point base Hadamard -- the TPU MXU's native tile -- with axis
-rearrangement between passes (DESIGN.md section 2).
+scalar butterfly stages, run dense matmul passes against Hadamard
+matrices sized for the TPU MXU (DESIGN.md section 2).
 
-The Pallas kernel in ``repro.kernels.hadacore`` implements the same pass
-structure with explicit VMEM tiling; this module is the portable path used
+The Pallas kernels in ``repro.kernels`` run the same pass function
+(``_apply_passes``) on VMEM tiles; this module is the portable path used
 inside models (it shards trivially under pjit because every op is a
-reshape/transpose/dot) and the reference for the kernel's pass math.
+reshape/transpose/dot) and the reference for the kernels' pass math.
 
-Factorization convention: n = 128^k * r with r = 2^m, 1 <= r < 128, and
+Factorization: with b = min(n, 128) and a = n / b,
 
-    H_n = H_128 (x) ... (x) H_128 (x) H_r        (Kronecker, r minor)
+    H_n = H_a (x) H_b                            (Kronecker, b minor)
 
-so the minor-axis pass touches contiguous lanes and every pass is a
-128-wide MXU matmul (the r-pass uses the paper's diagonal tiling trick:
-I_{128/r} (x) H_r as a 128x128 matrix -- section 3.3 of the paper).
+so a row, viewed as an (a, b) matrix X, transforms as H_a X H_b: one
+matmul over the 128 contiguous lanes, one over the a rows.
 """
 from __future__ import annotations
 
 import math
 from functools import partial
-from typing import List, Optional, Tuple
+from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,9 +31,10 @@ from repro.kernels.ref import hadamard_matrix, is_pow2
 __all__ = [
     "MXU_TILE",
     "COMPUTE_DTYPES",
-    "factorize",
     "base_matrices",
     "base_matrices_np",
+    "pack_pass_mats",
+    "unpack_pass_mats",
     "hadamard_transform",
     "grouped_hadamard",
     "largest_pow2_divisor",
@@ -97,43 +96,46 @@ def resolve_scale(scale, n: int) -> Optional[float]:
     raise ValueError(f"unknown Hadamard scale {scale!r}")
 
 
-def factorize(n: int) -> Tuple[int, int]:
-    """n = 128^k * r with r = 2^m < 128. Returns (k, r)."""
+def base_matrices_np(n: int, scale: Optional[float]) -> List[np.ndarray]:
+    """The transform's matrices (numpy f32), lane factor FIRST.
+
+    Sylvester Hadamards compose by Kronecker product, so with
+    ``b = min(n, 128)`` and ``a = n / b``, ``H_n = H_a (x) H_b``: viewing
+    a row as an (a, b) matrix X (row-major), the transform is
+    ``H_a X H_b``. Returns ``[H_b]`` for n <= 128 and ``[H_b, H_a]``
+    otherwise (a <= 256 under the kernel cap). ``scale`` is folded into
+    the first matrix -- a free normalization, one of the
+    micro-optimizations the scalar algorithm pays a full extra pass (or
+    per-stage multiply) for.
+    """
     if not is_pow2(n):
         raise ValueError(f"Hadamard size must be a power of 2, got {n}")
-    k = 0
-    while n % MXU_TILE == 0 and n > MXU_TILE:
-        # peel 128-factors but keep at least one factor (handled below)
-        n //= MXU_TILE
-        k += 1
-    if n == MXU_TILE:
-        return k + 1, 1
-    return k, n
-
-
-def base_matrices_np(n: int, scale: Optional[float]) -> List[np.ndarray]:
-    """Per-pass base matrices (numpy f32), minor-axis pass FIRST.
-
-    All matrices are 128x128 when n >= 128 (the r-pass is the
-    block-diagonal tiling I_{128/r} (x) H_r). For n < 128 a single n x n
-    matrix is returned. ``scale`` is folded into the first pass matrix --
-    a free normalization, one of the micro-optimizations the scalar
-    algorithm pays a full extra pass (or per-stage multiply) for.
-    """
-    k, r = factorize(n)
-    mats: List[np.ndarray] = []
-    if n < MXU_TILE:
-        mats.append(hadamard_matrix(n))
-    else:
-        if r > 1:
-            tiled = np.kron(np.eye(MXU_TILE // r, dtype=np.float32), hadamard_matrix(r))
-            mats.append(tiled)
-        else:
-            mats.append(hadamard_matrix(MXU_TILE))
-            k -= 1
-        mats.extend(hadamard_matrix(MXU_TILE) for _ in range(k))
+    b = min(n, MXU_TILE)
+    mats = [hadamard_matrix(b)]
+    if n > b:
+        mats.append(hadamard_matrix(n // b))
     if scale is not None:
         mats[0] = mats[0] * np.float32(scale)
+    return mats
+
+
+def pack_pass_mats(mats: List[np.ndarray]) -> np.ndarray:
+    """Stack the pass matrices into one (P, c, c) array, zero-padded to
+    the largest -- the single matrix operand every kernel takes."""
+    c = max(m.shape[0] for m in mats)
+    out = np.zeros((len(mats), c, c), np.float32)
+    for i, m in enumerate(mats):
+        out[i, :m.shape[0], :m.shape[1]] = m
+    return out
+
+
+def unpack_pass_mats(packed, n: int) -> list:
+    """Inverse of ``pack_pass_mats`` for an n-point plan; works on numpy
+    arrays, jax arrays and Pallas refs alike (static slices only)."""
+    b = min(n, MXU_TILE)
+    mats = [packed[0, :b, :b]]
+    if n > b:
+        mats.append(packed[1, :n // b, :n // b])
     return mats
 
 
@@ -143,37 +145,30 @@ def base_matrices(n: int, scale: Optional[float], dtype=jnp.float32) -> List[jnp
 
 
 def _apply_passes(x: jnp.ndarray, n: int, mats: List[jnp.ndarray]) -> jnp.ndarray:
-    """Shared pass structure: minor-axis matmul, then one matmul per major
-    128-factor with a transpose-in/transpose-out around each. ``x`` has
-    shape (M, n) and is already in the COMPUTE dtype (f32, bf16 or fp16);
-    every matmul accumulates in f32 on the MXU (``preferred_element_type``)
-    and inter-pass intermediates stay in the compute dtype. Runs unchanged
-    inside the Pallas kernel body and under plain jit."""
+    """Shared pass structure ``H_a X H_b`` (``base_matrices_np``): one
+    matmul over the 128 lanes of each (a, 128) row tile, then one over
+    its a sublane rows, which a minor-axis transpose brings to the lanes.
+    Every operand keeps 128 lanes or the full tile, the layouts Mosaic
+    lowers. ``x`` has shape (M, n) and is already in the COMPUTE dtype
+    (f32, bf16 or fp16); every matmul accumulates in f32 on the MXU
+    (``preferred_element_type``), the transposes move f32 values, and
+    the result of each pass is rounded to the compute dtype. Runs
+    unchanged inside the Pallas kernel body and under plain jit."""
     m = x.shape[0]
     cd = x.dtype
     mats = [mt if mt.dtype == cd else mt.astype(cd) for mt in mats]
 
     def mm(a, b):
-        return jnp.dot(a, b, preferred_element_type=jnp.float32).astype(cd)
+        return jnp.dot(a, b, preferred_element_type=jnp.float32)
 
-    if n < MXU_TILE:
-        return mm(x, mats[0])
-    # minor pass: contiguous 128-lane chunks
-    x = mm(x.reshape(m * (n // MXU_TILE), MXU_TILE), mats[0]).reshape(m, n)
-    # major passes: factor i acts on an axis of size 128 with `post`
-    # trailing elements; pre * 128 * post == n
-    num_major = len(mats) - 1
-    post = n // MXU_TILE
-    pre = 1
-    for i in range(num_major):
-        xv = x.reshape(m * pre, MXU_TILE, post)
-        xv = jnp.swapaxes(xv, -1, -2).reshape(m * pre * post, MXU_TILE)
-        xv = mm(xv, mats[i + 1])
-        xv = jnp.swapaxes(xv.reshape(m * pre, post, MXU_TILE), -1, -2)
-        x = xv.reshape(m, n)
-        pre *= MXU_TILE
-        post //= MXU_TILE
-    return x
+    if len(mats) == 1:
+        return mm(x, mats[0]).astype(cd)
+    a = n // MXU_TILE
+    y = mm(x.reshape(m * a, MXU_TILE), mats[0]).astype(cd)
+    y = jnp.swapaxes(y.astype(jnp.float32).reshape(m, a, MXU_TILE), 1, 2)
+    y = mm(y.reshape(m * MXU_TILE, a).astype(cd), mats[1])
+    y = jnp.swapaxes(y.reshape(m, MXU_TILE, a), 1, 2)
+    return y.reshape(m, n).astype(cd)
 
 
 @partial(jax.jit, static_argnames=("scale",))

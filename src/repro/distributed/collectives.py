@@ -14,7 +14,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+
+from repro.jaxapi import shard_map
 
 
 def _ring_body(x_local: jnp.ndarray, axis: str):
@@ -57,7 +58,6 @@ def int8_ring_all_reduce(contribs: jnp.ndarray, mesh: Mesh, axis: str) -> jnp.nd
         shard_map, mesh=mesh,
         in_specs=P(axis),
         out_specs=P(axis),
-        check_rep=False,
     )
     def run(xs):
         red = _ring_body(xs[0], axis)

@@ -8,6 +8,8 @@ Default rules:
   batch   -> (pod, data)        FSDP/DP axes
   fsdp    -> (pod, data)        parameter & optimizer-state sharding (ZeRO-3)
   heads/kv/dff/vocab/experts -> model   (tensor / expert parallel)
+  qdout   -> (pod, data, model) out-channels of a rotated quant_dot
+                                weight (see models/mlp.py)
   embed/seq -> replicated (overridable per launch config, e.g. long-context
   decode shards the KV-cache sequence dim)
 
@@ -38,6 +40,11 @@ DEFAULT_RULES: Dict[str, Axis] = {
     "dff": "model",
     "vocab": "model",
     "experts": "model",
+    # the out-channels of a weight that a rotated quant_dot consumes: the
+    # Hadamard spans its contraction axis, so the site shard_maps over
+    # the out-channels only, and the weight is stored split the same way
+    # over every axis -- never gathered for the call
+    "qdout": ("pod", "data", "model"),
     "embed": None,
     "seq": None,
     "seqpar": None,   # residual-stream sequence parallelism (opt-in)

@@ -31,10 +31,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.api import plan_for
+from repro.jaxapi import interpret_mode
 from repro.kernels.ref import is_pow2
 from repro.kernels.registry import (  # noqa: F401  (re-exported: legacy API)
     MAX_KERNEL_SIZE,
@@ -59,8 +59,9 @@ def hadacore(
       x: (..., n) with n a power of 2, n <= 32768 for the kernel path.
       scale: "ortho" (1/sqrt(n) rotation) or None (+-1 transform).
       block_m: rows per grid step (None = VMEM-budget heuristic).
-      interpret: run the kernel body in interpret mode (None = auto: True
-        off-TPU so CPU CI validates the same kernel code path).
+      interpret: run the kernel body in interpret mode (None = auto:
+        compiled on a TPU, interpreted on the CPU; see
+        ``jaxapi.interpret_mode``).
       in_place: alias the output onto the input buffer (Appendix B).
     """
     n = x.shape[-1]
@@ -72,7 +73,7 @@ def hadacore(
     if not is_pow2(n):
         raise ValueError(f"Hadamard size must be a power of 2, got {n}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     plan = plan_for(
         n, dtype=x.dtype, scale=scale, backend="pallas", block_m=block_m
     )

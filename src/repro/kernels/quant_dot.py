@@ -103,7 +103,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.hadamard import _apply_passes
+from repro.core.hadamard import _apply_passes, unpack_pass_mats
+from repro.jaxapi import ANY, tpu_compiler_params
 from repro.kernels.registry import (
     QSPECS,
     TRACE_COUNTS,
@@ -128,9 +129,25 @@ _CONTRACT = (((1,), (0,)), ((), ()))  # plain (m, k) @ (k, n)
 # the above-cap XLA fallback can exceed this -- the kernel caps at 2^15.
 _INT32_SAFE_K = 1 << 17
 
-# fp8 operand bytes/element inside the kernel: the 1-byte storage grid
-# plus the exact bf16 embedding the dot runs in.
-_FP8_OPERAND_BYTES = 3
+# fp8 weight-tile bytes/element inside the kernel: the 1-byte storage
+# grid, the f32 value its conversion to bf16 goes through on a chip
+# without fp8 arithmetic (v5e), and the exact bf16 embedding the dot
+# runs in. Charged at 3 (no f32), an n=2048 tile of 1024 out-channels
+# overflowed the 16 MiB scoped VMEM at any row block.
+_FP8_OPERAND_BYTES = 7
+
+# f32 bytes/element of the row block that the j == 0 rotate+quantize
+# holds at once: the pass output, its minor-axis transpose and the
+# quantizer's quotient. Mosaic allocates them for the whole kernel, so
+# leaving them out let fp8 prefill tiles past the compiler's 16 MiB
+# scoped-VMEM limit on v5e.
+_ROTATE_TEMP_BYTES = 12
+
+# Below this n the a = n/128 sublane rows of a row tile are too few to
+# fill the lanes of the transposed (128, a) temporary, which Mosaic then
+# pads: a row's temporaries cost what they cost at this n, not less (on
+# v5e the n=512 transform compiles at 256 rows a block and not at 512).
+_ROTATE_TEMP_MIN_N = 4096
 
 SCHEDULE_ENV_VAR = "REPRO_QUANT_DOT_SCHEDULE"
 SCHEDULES = ("rotate_once", "revisit", "streamed")
@@ -213,7 +230,10 @@ class BlockDecision(tuple):
       (the streamed DMA ring costs a second weight-tile slot + a scale
       ring, so its block sizes can be narrower);
     * ``vmem_bytes`` -- the estimated VMEM high-water mark of the chosen
-      tiles under that schedule (<= the kernel budget by construction).
+      tiles under that schedule: within the kernel budget, except where
+      the smallest tile (one sublane group of rows x 128 out-channels)
+      exceeds it and is returned anyway -- fp8 at n=8192 is charged
+      9.7 MiB, which Mosaic compiles within its 16 MiB limit.
     """
 
     schedule: str
@@ -289,8 +309,9 @@ def quant_dot_blocks(n: int, d: int, m: int, dtype, compute_dtype,
         wb += 1
         swb *= 2
     # per-row residents independent of bn: input tile + compute copy +
-    # scratch operand + f32 scratch scale
-    row_fixed = n * (in_b + cb + qb) + 4
+    # scratch operand + the rotation's f32 temporaries + f32 scratch scale
+    row_fixed = (n * (in_b + cb + qb) + 4
+                 + _ROTATE_TEMP_BYTES * max(n, _ROTATE_TEMP_MIN_N))
     fixed = 0
     if abft:
         row_fixed += 12             # chk + acc scratch + residual out tile
@@ -331,7 +352,7 @@ def _rotate_quantize_block(x, mats_ref, *, n: int, mode: str,
     f32-grid form."""
     x = x.astype(compute_dtype)
     bm = x.shape[0]
-    mats = [mats_ref[p] for p in range(mats_ref.shape[0])]
+    mats = unpack_pass_mats(mats_ref, n)
     y = _apply_passes(x.reshape(bm, n), n, mats)
     return _quantize_rows(y.astype(jnp.float32), mode)
 
@@ -570,9 +591,10 @@ def _resolve_schedule(schedule, interpret: bool = False) -> str:
     ``REPRO_QUANT_DOT_SCHEDULE`` env override, then ``rotate_once`` (the
     default until the bench gate shows the streamed win on hardware).
 
-    ``streamed`` needs a real DMA engine; under ``interpret=True`` (any
-    backend without async copies runs the kernels through the XLA
-    interpreter) it degrades to ``rotate_once`` -- warned once per
+    ``streamed`` needs a real DMA engine; under ``interpret=True`` (the
+    CPU: ``jaxapi.interpret_mode`` never picks it on a TPU, so the chip
+    runs the schedule it is asked for) it degrades to ``rotate_once`` --
+    warned once per
     process, counted in ``TRACE_COUNTS[("quant_dot", "stream_fallback")]``
     on every dispatch -- unless ``REPRO_QUANT_DOT_STREAM_INTERPRET`` is
     set, which runs the real streamed body on the interpreter's
@@ -672,8 +694,8 @@ def _pallas_quant_dot(x, wq, sw, plan, interpret: bool, schedule: str,
                    pltpu.VMEM((2, 1, bn), jnp.float32),    # scale ring
                    pltpu.SemaphoreType.DMA((2,)),          # weight sems
                    pltpu.SemaphoreType.DMA((2,))]          # scale sems
-        wq_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        sw_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        wq_spec = pl.BlockSpec(memory_space=ANY)
+        sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
         kernel = functools.partial(_quant_dot_kernel_revisit, **common)
         scratch = []
@@ -690,8 +712,7 @@ def _pallas_quant_dot(x, wq, sw, plan, interpret: bool, schedule: str,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, dp), jnp.dtype(plan.dtype)),
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(x2, mats, wq2, sw2)
     return out[:m, :d].reshape(*lead, d)
@@ -755,8 +776,8 @@ def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
             pltpu.VMEM((2, 1, bn), jnp.float32),    # scale ring
             pltpu.SemaphoreType.DMA((2,)),          # weight sems
             pltpu.SemaphoreType.DMA((2,))]          # scale sems
-        wq_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        sw_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        wq_spec = pl.BlockSpec(memory_space=ANY)
+        sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
         kernel = functools.partial(_quant_dot_kernel_revisit_abft, **common)
         scratch = [pltpu.VMEM((bm, 1), jnp.float32)]        # acc only
@@ -776,8 +797,7 @@ def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
         out_shape=[jax.ShapeDtypeStruct((mp, dp), jnp.dtype(plan.dtype)),
                    jax.ShapeDtypeStruct((mp, 1), jnp.float32)],
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=tpu_compiler_params("parallel", "arbitrary"),
         interpret=interpret,
     )(x2, mats, wq2, sw2, cw2)
     return (out[:m, :d].reshape(*lead, d),
@@ -975,8 +995,8 @@ def _pallas_quant_dot_experts(x, wq, sw, plan, interpret: bool,
                     pltpu.VMEM((2, 1, bn), jnp.float32),   # scale ring
                     pltpu.SemaphoreType.DMA((2,)),         # weight sems
                     pltpu.SemaphoreType.DMA((2,))]         # scale sems
-        wq_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        sw_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        wq_spec = pl.BlockSpec(memory_space=ANY)
+        sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
         # revisit never grew a 3-D body (the A/B baseline is 2-D only):
         # anything else runs the rotate-once step
@@ -995,8 +1015,8 @@ def _pallas_quant_dot_experts(x, wq, sw, plan, interpret: bool,
         out_specs=pl.BlockSpec((1, bm, bn), lambda e, i, j: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, mp, dp), jnp.dtype(plan.dtype)),
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu_compiler_params(
+            "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(x3, mats, wq3, sw3)
     out = jnp.moveaxis(out[:, :m, :d].reshape(E, -1, cap, d), 0, 1)
@@ -1048,8 +1068,8 @@ def _pallas_quant_dot_experts_abft(x, wq, sw, cw, plan, interpret: bool,
                     pltpu.VMEM((2, 1, bn), jnp.float32),   # scale ring
                     pltpu.SemaphoreType.DMA((2,)),         # weight sems
                     pltpu.SemaphoreType.DMA((2,))]         # scale sems
-        wq_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-        sw_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        wq_spec = pl.BlockSpec(memory_space=ANY)
+        sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
         kernel = functools.partial(_quant_dot_experts_kernel_abft, n=n,
                                    mode=mode, compute_dtype=cd)
@@ -1069,8 +1089,8 @@ def _pallas_quant_dot_experts_abft(x, wq, sw, cw, plan, interpret: bool,
         out_shape=[jax.ShapeDtypeStruct((E, mp, dp), jnp.dtype(plan.dtype)),
                    jax.ShapeDtypeStruct((E, mp, 1), jnp.float32)],
         scratch_shapes=scratch,
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=tpu_compiler_params(
+            "parallel", "parallel", "arbitrary"),
         interpret=interpret,
     )(x3, mats, wq3, sw3, cw3)
     out = jnp.moveaxis(out[:, :m, :d].reshape(E, -1, cap, d), 0, 1)

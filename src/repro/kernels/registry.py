@@ -38,9 +38,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.hadamard import MXU_TILE, _apply_passes
+from repro.core.hadamard import MXU_TILE, _apply_passes, unpack_pass_mats
 from repro.kernels.ref import fwht
 
 __all__ = [
@@ -64,7 +65,9 @@ BACKEND_ENV_VAR = "REPRO_HADAMARD_BACKEND"
 # (block_m, n) row tile would still fit VMEM only for tiny block_m.
 MAX_KERNEL_SIZE = 32768
 
-# VMEM budget we tile for (v5e has 16 MiB more or less reserved for Pallas).
+# VMEM the tile heuristics may fill with a kernel's counted residents:
+# half of Mosaic's default 16 MiB scoped-VMEM limit on v5e, so the
+# pipeline's second buffer of each blocked operand fits beside them.
 _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 # mode -> (grid max, storage dtype, integer grid?). The fused kernel and
@@ -171,9 +174,11 @@ def select_backend(p: int, requested: Optional[str] = None) -> str:
 
     Explicit request > ``REPRO_HADAMARD_BACKEND`` env var > auto (priority
     order over backends whose ``supports(p)`` holds). A requested backend
-    that cannot run the size falls through to auto selection -- preserving
-    the historical ``hadamard(x, backend="pallas")`` -> XLA fallback for
-    n above the kernel cap.
+    that cannot run the size falls through to auto selection (e.g.
+    ``backend="pallas"`` above the kernel cap runs on XLA) -- warned once
+    per process and counted in
+    ``TRACE_COUNTS[("backend_fallback", <requested>)]`` on every plan
+    build, so a kernel that silently stopped running shows up.
     """
     if requested in (None, "auto"):
         requested = os.environ.get(BACKEND_ENV_VAR) or None
@@ -184,6 +189,13 @@ def select_backend(p: int, requested: Optional[str] = None) -> str:
     for name in available_backends():
         be = _REGISTRY[name]
         if be.auto and be.supports(p):
+            if requested is not None:
+                warn_once(
+                    ("backend_fallback", requested),
+                    f"Hadamard backend {requested!r} cannot run a {p}-point "
+                    f"transform; using {name!r} (warned once per process; "
+                    f"TRACE_COUNTS[('backend_fallback', {requested!r})] "
+                    "keeps counting)")
             return name
     raise ValueError(f"no registered backend supports a {p}-point transform")
 
@@ -218,6 +230,10 @@ class Backend:
     # semantics (xla) -- the sharded dispatcher uses this to warn when a
     # mesh plan silently loses the fused hot path.
     quant_dot_fused = False
+    # Are the kernels Pallas (Mosaic) calls? GSPMD cannot partition those,
+    # so the dispatcher runs them under ``shard_map`` when a mesh is
+    # active (``core.api._on_rows``).
+    mosaic = False
 
 
 # ---------------------------------------------------------------- kernels
@@ -229,7 +245,7 @@ def _hadacore_kernel(x_ref, mats_ref, o_ref, *, n: int, compute_dtype):
     passes accumulate f32 on the MXU (``_apply_passes``)."""
     x = x_ref[...].astype(compute_dtype)
     bm = x.shape[0]
-    mats = [mats_ref[p] for p in range(mats_ref.shape[0])]
+    mats = unpack_pass_mats(mats_ref, n)
     y = _apply_passes(x.reshape(bm, n), n, mats)
     o_ref[...] = y.reshape(x_ref.shape).astype(o_ref.dtype)
 
@@ -247,7 +263,12 @@ def _quantize_rows(y: jnp.ndarray, mode: str, axis=-1):
     per-tensor scale (never fusable: needs a global reduction).
     """
     qmax, _, is_int = QSPECS[mode]
-    s = jnp.maximum(jnp.max(jnp.abs(y), axis=axis, keepdims=True), 1e-8) / qmax
+    # multiply by the f32 reciprocal of the grid maximum rather than
+    # divide: XLA turns a division by a constant into this multiply in
+    # some programs and not in others, which left the scales of two
+    # programs one ulp apart; written out, every program rounds alike
+    s = (jnp.maximum(jnp.max(jnp.abs(y), axis=axis, keepdims=True), 1e-8)
+         * np.float32(1.0 / qmax))
     q = y / s
     if is_int:
         q = jnp.clip(jnp.round(q), -qmax, qmax)
@@ -273,7 +294,7 @@ def _fused_kernel(x_ref, mats_ref, q_ref, s_ref, *, n: int, mode: str,
     the plan's compute dtype; the epilogue statistics stay f32."""
     x = x_ref[...].astype(compute_dtype)
     bm = x.shape[0]
-    mats = [mats_ref[p] for p in range(mats_ref.shape[0])]
+    mats = unpack_pass_mats(mats_ref, n)
     y = _apply_passes(x.reshape(bm, n), n, mats)
     q, s = _quantize_rows(y.astype(jnp.float32), mode)
     q_ref[...] = q.astype(q_ref.dtype)
@@ -288,7 +309,7 @@ def _fused_dequant_kernel(x_ref, mats_ref, o_ref, *, n: int, mode: str,
     round-trip through the real storage dtype."""
     x = x_ref[...].astype(compute_dtype)
     bm = x.shape[0]
-    mats = [mats_ref[p] for p in range(mats_ref.shape[0])]
+    mats = unpack_pass_mats(mats_ref, n)
     y = _apply_passes(x.reshape(bm, n), n, mats)
     q, s = _quantize_rows(y.astype(jnp.float32), mode)
     o_ref[...] = _dequantize(q, s, mode).reshape(x_ref.shape).astype(o_ref.dtype)
@@ -402,6 +423,7 @@ class PallasBackend(Backend):
     name = "pallas"
     priority = 20
     quant_dot_fused = True
+    mosaic = True
 
     def supports(self, p: int) -> bool:
         return p <= MAX_KERNEL_SIZE
@@ -439,7 +461,8 @@ def _xla_transform(x, plan):
     TRACE_COUNTS[("xla", "transform")] += 1
     n = plan.p
     cd = jnp.dtype(plan.compute_dtype)
-    mats = [jnp.asarray(m, dtype=cd) for m in plan.mats]
+    mats = [jnp.asarray(m, dtype=cd)
+            for m in unpack_pass_mats(plan.mats, n)]
     orig_shape, orig_dtype = x.shape, x.dtype
     x2, _ = _rows(x.astype(cd), n)
     y = _apply_passes(x2, n, mats)

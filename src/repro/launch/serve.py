@@ -19,7 +19,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.quant import QuantConfig
 from repro.launch import shapes as shp
-from repro.launch.env import harden_host_env
+from repro.launch.env import enable_compile_cache, harden_host_env
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import (
     jit_prefill_step,
@@ -33,6 +33,7 @@ from repro.models.lm import pad_kv_caches
 
 def main(argv=None):
     harden_host_env()                 # flags only; re-exec is __main__'s
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--scale", type=float, default=0.02)

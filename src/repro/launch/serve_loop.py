@@ -26,15 +26,17 @@ import jax
 
 from repro.configs import get_config
 from repro.core.quant import QuantConfig
-from repro.launch.env import harden_host_env
+from repro.launch.env import enable_compile_cache, harden_host_env
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import make_param_init, param_shardings
 from repro.launch.train import scaled_config
 from repro.serving import ServeEngine, synthetic_stream
 
 
-def build_engine(args, cfg=None):
-    """Config -> (engine, cfg): shared by the CLI and the bench suite."""
+def build_engine(args, cfg=None, mesh=None):
+    """Config -> (engine, cfg): shared by the CLI, the bench suite and
+    ``chip_smoke.py``. ``mesh`` defaults to ``make_local_mesh(args.mp)``
+    over every device."""
     if cfg is None:
         quant = QuantConfig(mode=args.quant, rotate=args.rotate,
                             backend=args.kernel,
@@ -45,7 +47,8 @@ def build_engine(args, cfg=None):
                     else args.prequant)
         if prequant:
             cfg = dataclasses.replace(cfg, weight_quant="int8")
-    mesh = make_local_mesh(args.mp)
+    if mesh is None:
+        mesh = make_local_mesh(args.mp)
     with mesh:
         ps = param_shardings(cfg, mesh)
         params = jax.jit(make_param_init(cfg), out_shardings=ps)(
@@ -59,8 +62,7 @@ def build_engine(args, cfg=None):
     return engine, cfg
 
 
-def main(argv=None):
-    harden_host_env()                 # flags only; re-exec is __main__'s
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--scale", type=float, default=0.02)
@@ -97,22 +99,21 @@ def main(argv=None):
     ap.add_argument("--watchdog-ms", type=float, default=None,
                     help="decode-step wall-clock bound; two consecutive "
                          "trips degrade the engine one ladder rung")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    engine, cfg = build_engine(args)
-    if cfg.weight_quant == "int8":
-        print("weights pre-quantized once at load (QTensor tree; "
-              f"consumer mode={cfg.quant.mode})")
-    t_compile = engine.warmup()
-    print(f"warmup: prefill/insert/decode compiled in {t_compile:.2f}s")
 
-    stream = synthetic_stream(
+def make_stream(args, cfg):
+    """The seeded arrival stream the arguments describe."""
+    return synthetic_stream(
         args.requests, vocab_size=cfg.vocab_size,
         prompt_len=(args.prompt_min, args.prompt_max or args.prefill_len),
         max_new_tokens=(args.gen_min, args.gen_max),
         rate=args.rate, seed=args.seed,
         deadline_slack=args.deadline_slack)
-    engine.run(stream)
+
+
+def report(engine):
+    """Print the run's summary lines; returns ``engine.summary()``."""
     s = engine.summary()
     print(f"served {s['requests']:.0f} requests / "
           f"{s['generated_tokens']:.0f} tokens in "
@@ -139,6 +140,21 @@ def main(argv=None):
           f"(constant across admissions/retirements), "
           f"quantize_weight_calls={s['quantize_weight_calls']:.0f} "
           f"during serve")
+    return s
+
+
+def main(argv=None):
+    harden_host_env()                 # flags only; re-exec is __main__'s
+    enable_compile_cache()
+    args = parse_args(argv)
+    engine, cfg = build_engine(args)
+    if cfg.weight_quant == "int8":
+        print("weights pre-quantized once at load (QTensor tree; "
+              f"consumer mode={cfg.quant.mode})")
+    t_compile = engine.warmup()
+    print(f"warmup: prefill/insert/decode compiled in {t_compile:.2f}s")
+    engine.run(make_stream(args, cfg))
+    report(engine)
     return engine
 
 

@@ -41,6 +41,7 @@ from repro.configs import get_config
 from repro.core.quant import QuantConfig
 from repro.data import SyntheticDataset
 from repro.launch import shapes as shp
+from repro.launch.env import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import jit_train_step, param_shardings
 from repro.models import init_lm
@@ -69,6 +70,7 @@ def scaled_config(cfg, scale: float):
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3-8b")
     ap.add_argument("--shape", default="train_4k")

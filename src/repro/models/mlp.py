@@ -20,10 +20,20 @@ from repro.distributed.sharding import constrain
 from repro.models.common import dense_init
 
 # Logical sharding axes of the down-projection weights -- the declarative
-# half of the consumer spec: under a mesh the out-channel ('fsdp') axis
-# folds into the quant_dot plan key and dispatch shards over it.
+# half of the consumer spec: under a mesh the out-channel axis folds into
+# the quant_dot plan key and dispatch shards over it. Where the site
+# rotates and quantizes, the Hadamard spans d_ff, so the weight is stored
+# by out-channel only ('qdout'): a tensor-parallel mesh, which would
+# otherwise hold it split over d_ff, never gathers it for the call.
 _DOWN_AXES = ("dff", "fsdp")
+_ROTATED_DOWN_AXES = (None, "qdout")
 _EXPERT_DOWN_AXES = ("experts", "dff", "fsdp")
+
+
+def down_axes(cfg):
+    """Logical axes of a dense down-projection weight (d_ff, d_model)."""
+    qc = cfg.quant
+    return _ROTATED_DOWN_AXES if qc.rotating and qc.enabled else _DOWN_AXES
 
 
 def _act(cfg, g):
@@ -43,7 +53,7 @@ def init_mlp(key, cfg):
 
 
 def mlp_specs(cfg):
-    p = {"w_up": ("fsdp", "dff"), "w_down": ("dff", "fsdp")}
+    p = {"w_up": ("fsdp", "dff"), "w_down": down_axes(cfg)}
     if cfg.act == "swiglu":
         p["w_gate"] = ("fsdp", "dff")
     return p
@@ -63,8 +73,8 @@ def apply_mlp(cfg, p, x):
     # on the fly (training), a pre-quantized QTensor is consumed directly
     # (serving -- zero per-forward weight quantization). Under a mesh the
     # dispatch shard_maps: activations row-sharded over the data axes,
-    # weight columns + scales over 'fsdp', the fused kernel shard-local ----
-    spec = QuantDotSpec.for_config(h.shape[-1], qc, weight_axes=_DOWN_AXES)
+    # weight columns + scales over 'qdout', the fused kernel shard-local --
+    spec = QuantDotSpec.for_config(h.shape[-1], qc, weight_axes=down_axes(cfg))
     y = spec.bind(p["w_down"])(h)
     return constrain(y, "batch", "seq", None)
 

@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.core.api import QuantDotSpec
 from repro.distributed.sharding import constrain
 from repro.models.common import dense_init
+from repro.models.mlp import down_axes
 
 _LORA = 32
 _MIXES = 5  # r, k, v, w, g
@@ -224,7 +225,7 @@ def init_rwkv_cmix(key, cfg):
 
 def rwkv_cmix_specs(cfg):
     return {"mu_r": (None,), "mu_k": (None,),
-            "wr": ("fsdp", None), "wk": ("fsdp", "dff"), "wv": ("dff", "fsdp")}
+            "wr": ("fsdp", None), "wk": ("fsdp", "dff"), "wv": down_axes(cfg)}
 
 
 def apply_rwkv_cmix(cfg, p, x, x_prev=None, *, return_state: bool = False):
@@ -245,7 +246,7 @@ def apply_rwkv_cmix(cfg, p, x, x_prev=None, *, return_state: bool = False):
     # directly on the serving path; under a mesh the dispatch shard_maps
     # with row-sharded activations and the fused kernel shard-local.
     spec = QuantDotSpec.for_config(k.shape[-1], cfg.quant,
-                                   weight_axes=("dff", "fsdp"))
+                                   weight_axes=down_axes(cfg))
     y = r * spec.bind(p["wv"])(k)
     y = constrain(y, "batch", "seq", None)
     if return_state:

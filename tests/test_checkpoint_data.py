@@ -49,7 +49,8 @@ def test_incomplete_checkpoint_ignored(tmp_path):
 def test_restore_with_shardings(tmp_path):
     """Elastic path: restore re-shards onto the current (1-device) mesh."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     t = {"w": jnp.ones((8, 4), jnp.float32)}
     save_checkpoint(str(tmp_path), 1, t, async_write=False)
     sh = {"w": NamedSharding(mesh, P("data", None))}

@@ -10,7 +10,8 @@ from repro.distributed.sharding import DEFAULT_RULES
 def test_resolver_divisibility_guard():
     import jax
     from repro.distributed.sharding import make_resolver
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     one = make_resolver(mesh)
     s = one(("batch", None), (4, 8))
     assert s.spec == jax.sharding.PartitionSpec(None, None) or True
@@ -40,7 +41,8 @@ batch = make_batch(cfg, shape)
 params = init_lm(jax.random.PRNGKey(0), cfg)
 loss_1dev, _ = lm_loss(cfg, params, batch)
 
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 opt = OptConfig(lr=1e-3)
 step, (ps, os_, bs) = jit_train_step(cfg, opt, shape, mesh, donate=False)
 params_s = jax.device_put(params, ps)
@@ -60,7 +62,8 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.distributed.collectives import int8_ring_all_reduce
 
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 rng = np.random.default_rng(0)
 contribs = jnp.asarray(rng.standard_normal((8, 32, 16)) * 5, jnp.float32)
 contribs = jax.device_put(contribs, NamedSharding(mesh, P("data")))
@@ -87,7 +90,8 @@ from repro.launch.steps import jit_train_step, param_shapes, opt_state_shapes
 from repro.optim import OptConfig
 from repro.launch.hlo_analysis import analyze_hlo
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 cfg = get_config("mixtral_8x7b").scaled_down()
 shape = ShapeSpec("t", "train", 64, 8)
 opt = OptConfig()
@@ -112,7 +116,8 @@ from repro.launch.shapes import cache_specs
 from repro.models import init_lm
 
 cfg = get_config("llama3_8b").scaled_down()
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 B, T = 4, 64
 serve, (ps, cs, ts) = jit_serve_step(cfg, B, T, mesh, donate=False)
 params = jax.device_put(init_lm(jax.random.PRNGKey(0), cfg), ps)
@@ -141,7 +146,8 @@ from repro.distributed import sharding as shd
 rng = np.random.default_rng(0)
 x = jnp.asarray(rng.standard_normal((16, 256)), jnp.float32)
 w = jnp.asarray(rng.standard_normal((256, 128)) * 0.05, jnp.float32)
-mesh = jax.make_mesh((2,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("model",))
 
 for mode, exact in (("int8", True), ("fp8_e4m3", False)):
     qt = quantize_weight(w, mode)
@@ -205,7 +211,8 @@ quant = QuantConfig(mode="int8", rotate="hadamard", backend="xla",
 cfg = dataclasses.replace(
     get_config("llama3_8b").scaled_down().with_quant(quant),
     weight_quant="int8")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 B, T = 4, 64
 serve, (ps, cs, ts) = jit_serve_step(cfg, B, T, mesh, donate=False)
 params = jax.jit(make_param_init(cfg), out_shardings=ps)(
@@ -220,6 +227,50 @@ assert np.isfinite(np.asarray(logits[..., :cfg.vocab_size], np.float32)).all()
 print("SERVE_QTENSOR_OK")
 """, devices=4)
     assert "SERVE_QTENSOR_OK" in out
+
+
+def test_tensor_parallel_rotated_down_proj_is_never_gathered(subproc):
+    """On a (1, 4) tensor-parallel mesh the rotated down-projection is
+    stored by out-channel and its quant_dot runs the fused kernel on
+    each device's columns: no device is handed the whole weight."""
+    out = subproc("""
+import dataclasses
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P
+from repro.configs import get_config
+from repro.core import api
+from repro.core.quant import QuantConfig
+from repro.kernels.registry import TRACE_COUNTS
+from repro.launch.mesh import make_local_mesh
+from repro.launch.shapes import cache_specs
+from repro.launch.steps import jit_serve_step, make_param_init
+
+from repro.launch.train import scaled_config
+
+quant = QuantConfig(mode="fp8_e4m3", rotate="hadamard", backend="pallas")
+cfg = dataclasses.replace(    # d_model 384, d_ff 1024: the kernel fuses
+    scaled_config(get_config("phi4-mini-3.8b"), 1 / 64).with_quant(quant),
+    weight_quant="int8")
+mesh = make_local_mesh(4)
+B, T = 4, 32
+serve, (ps, cs, ts) = jit_serve_step(cfg, B, T, mesh, donate=False)
+wd = ps["groups"][0]["p0"]["mlp"]["w_down"]
+assert wd.q.spec == P(None, None, ("data", "model")), wd.q.spec
+params = jax.jit(make_param_init(cfg), out_shardings=ps)(
+    jax.random.PRNGKey(0))
+caches = jax.device_put(jax.tree.map(
+    lambda s: jnp.zeros(s.shape, s.dtype), cache_specs(cfg, B, T)), cs)
+toks = jax.device_put(jnp.ones((B, 1), jnp.int32), ts)
+key = ("sharded_quant_dot", "replicated_operand")
+before = TRACE_COUNTS[key]
+_, logits, _ = serve(params, caches, toks, jnp.asarray(3, jnp.int32))
+assert TRACE_COUNTS[key] == before, TRACE_COUNTS[key]
+disp = api._LAST_SHARDED_DISPATCH
+assert disp["fused"] and disp["mesh_axes"] == ("data", "model"), disp
+assert np.isfinite(np.asarray(logits[..., :cfg.vocab_size], np.float32)).all()
+print("TP_DOWN_PROJ_OK")
+""", devices=4)
+    assert "TP_DOWN_PROJ_OK" in out
 
 
 def test_sharded_quant_dot_fused_shard_local_2dev(subproc):
@@ -240,7 +291,8 @@ x = jnp.asarray(rng.standard_normal((16, 256)), jnp.float32)
 w = jnp.asarray(rng.standard_normal((256, 128)) * 0.05, jnp.float32)
 qt = quantize_weight(w, "int8")
 ref = quant_dot(x, qt, mode="int8", backend="pallas")     # single device
-mesh = jax.make_mesh((1, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((1, 2), ("data", "model"))
 unfused_before = registry.TRACE_COUNTS[("sharded_quant_dot", "unfused_local")]
 kernel_before = registry.TRACE_COUNTS[("pallas", "quant_dot")]
 with shd.sharding_rules(mesh):
@@ -285,7 +337,8 @@ from repro.distributed import sharding as shd
 rng = np.random.default_rng(1)
 w = jnp.asarray(rng.standard_normal((256, 128)) * 0.05, jnp.float32)
 qt = quantize_weight(w, "int8")
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2, 2), ("data", "model"))
 for rows, want_axes in ((16, ("data",)), (9, ())):
     x = jnp.asarray(rng.standard_normal((rows, 256)), jnp.float32)
     ref = quant_dot(x, qt, mode="int8", backend="pallas")
@@ -317,7 +370,8 @@ rng = np.random.default_rng(2)
 x = jnp.asarray(rng.standard_normal((8, 256)), jnp.float32)
 qt = quantize_weight(
     jnp.asarray(rng.standard_normal((256, 128)) * 0.05, jnp.float32), "int8")
-mesh = jax.make_mesh((2,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((2,), ("model",))
 
 key_u = ("sharded_quant_dot", "unfused_local")
 with warnings.catch_warnings(record=True) as wl:
@@ -383,8 +437,54 @@ def test_sharded_quant_dot_in_main_process():
     w = jnp.asarray(rng.standard_normal((128, 64)) * 0.05, jnp.float32)
     qt = quantize_weight(w, "int8")
     ref = quant_dot(x, qt, mode="int8", backend="xla")
-    mesh = jax.make_mesh((2,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("model",))
     with shd.sharding_rules(mesh):
         out = quant_dot(x, qt, mode="int8", backend="xla",
                         weight_axes=(None, "dff"))
     assert (np.asarray(out) == np.asarray(ref)).all()
+
+
+def test_pallas_kernels_run_under_shard_map_on_a_mesh(subproc):
+    """Mosaic kernels cannot be partitioned by GSPMD: under a multi-device
+    mesh every Pallas call -- the fused QK rotate+quantize and a
+    quant_dot whose weight is not sharded by out-channel -- runs inside a
+    shard_map over the rows, with results bitwise the single-device
+    ones. The quant_dot's whole weight on every device is counted."""
+    out = subproc("""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core.api import QuantEpilogue, hadamard, plan_for, quant_dot
+from repro.core.wquant import quantize_weight
+from repro.distributed import sharding as shd
+from repro.kernels.registry import TRACE_COUNTS
+from repro.launch.mesh import make_mesh
+
+rng = np.random.default_rng(3)
+mesh = make_mesh((1, 2), ("data", "model"))
+q = jnp.asarray(rng.standard_normal((4, 1, 6, 128)), jnp.float32)
+plan = plan_for(128, backend="pallas",
+                epilogue=QuantEpilogue("fp8_e4m3", dequant=True))
+x = jnp.asarray(rng.standard_normal((8, 256)), jnp.float32)
+qt = quantize_weight(
+    jnp.asarray(rng.standard_normal((256, 64)) * 0.05, jnp.float32), "int8")
+
+def run(q, x):
+    return hadamard(q, plan), quant_dot(x, qt, mode="int8", backend="pallas")
+
+def on_mesh(q, x):
+    with shd.sharding_rules(mesh):
+        return run(q, x)
+
+want = jax.jit(run)(q, x)
+key = ("sharded_quant_dot", "replicated_operand")
+before = TRACE_COUNTS[key]
+jaxpr = str(jax.make_jaxpr(on_mesh)(q, x))
+assert jaxpr.count("shard_map") == 2, jaxpr.count("shard_map")
+assert TRACE_COUNTS[key] == before + 1     # the quant_dot, not the QK site
+got = jax.jit(on_mesh)(q, x)
+for g, w in zip(got, want):
+    assert g.shape == w.shape
+    assert (np.asarray(g) == np.asarray(w)).all()
+print("SHARD_MAP_KERNELS_OK")
+""", devices=2)
+    assert "SHARD_MAP_KERNELS_OK" in out

@@ -9,7 +9,13 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.hadamard import factorize, grouped_hadamard, hadamard_transform
+from repro.core.hadamard import (
+    base_matrices_np,
+    grouped_hadamard,
+    hadamard_transform,
+    pack_pass_mats,
+    unpack_pass_mats,
+)
 from repro.kernels.fused_quant import fused_hadamard_quantize, ref_fused
 from repro.kernels.hadacore import hadacore
 from repro.kernels.ops import hadamard
@@ -83,14 +89,20 @@ def test_kernel_size_cap():
     assert y.shape == (2, 65536)
 
 
-def test_factorize():
-    assert factorize(128) == (1, 1)
-    assert factorize(256) == (1, 2)
-    assert factorize(16384) == (2, 1)
-    assert factorize(32768) == (2, 2)
-    assert factorize(64) == (0, 64)
+@pytest.mark.parametrize("n", [2, 64, 128, 256, 2048])
+def test_pass_matrices_compose_to_hadamard(n):
+    """H_n = H_a (x) H_b with b = min(n, 128), and the packed kernel
+    operand unpacks to the same matrices."""
+    mats = base_matrices_np(n, None)
+    assert [m.shape[0] for m in mats] == [min(n, 128)] + (
+        [n // 128] if n > 128 else [])
+    full = mats[0] if len(mats) == 1 else np.kron(mats[1], mats[0])
+    np.testing.assert_array_equal(full, hadamard_matrix(n))
+    for m, u in zip(mats, unpack_pass_mats(pack_pass_mats(mats), n),
+                    strict=True):
+        np.testing.assert_array_equal(u, m)
     with pytest.raises(ValueError):
-        factorize(96)
+        base_matrices_np(3 * n, None)
 
 
 # --------------------------------------------------------------- properties

@@ -147,7 +147,8 @@ def test_collectives_detected_inside_scan(subproc):
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import analyze_hlo
-mesh = jax.make_mesh((4,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((4,), ("data",))
 L, D = 5, 256
 def f(x, w):
     def body(c, wi):

@@ -55,11 +55,11 @@ def test_repeated_shapes_do_not_recompile(monkeypatch):
 
 def test_plan_precomputes_factorization():
     p = plan_for(32768, backend="pallas")
-    assert (p.k, p.r) == (2, 2)
-    assert p.mats.shape[0] == 3 and p.mats.shape[-1] == 128
+    # H_256 (x) H_128: a 128-lane pass and a 256-row pass, packed into
+    # one (2, 256, 256) operand
+    assert p.num_passes == 2 and p.mats.shape == (2, 256, 256)
     small = plan_for(64, backend="pallas")
-    assert (small.k, small.r) == (0, 64)
-    assert small.mats.shape == (1, 64, 64)
+    assert small.num_passes == 1 and small.mats.shape == (1, 64, 64)
     grouped = plan_for(14336)  # 7 * 2048
     assert grouped.grouped and grouped.p == 2048
     assert isinstance(grouped, HadamardPlan)

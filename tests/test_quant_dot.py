@@ -453,7 +453,8 @@ def test_quant_dot_experts_einsum_under_mesh():
     qt = quantize_weight(_x((2, 256, 64), seed=45) * 0.1, "int8")
     plan = plan_for(256, backend="pallas", epilogue=QuantEpilogue("int8"))
     off_mesh = quant_dot_experts(x, qt, plan)
-    mesh = jax.make_mesh((1,), ("model",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("model",))
     key = ("pallas", "quant_dot_experts")
     obs = ("sharded_quant_dot", "experts_einsum_on_mesh")
     with shd.sharding_rules(mesh):

@@ -2,6 +2,7 @@
 bitwise parity with the one-shot serve path, slot-reuse hygiene, retrace
 and prequant invariants, env hardening, CLI + bench smoke."""
 import dataclasses
+import os
 
 import numpy as np
 import pytest
@@ -281,6 +282,31 @@ def test_harden_host_env_opt_out_and_preservation(monkeypatch):
         "--foo --xla_force_host_platform_device_count=4"
     assert "LD_PRELOAD" not in env                     # no tcmalloc found
     assert "XLA_FLAGS" in applied
+
+
+def test_enable_compile_cache_placement():
+    from repro.launch import env as env_mod
+
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    min_s = jax.config.jax_persistent_cache_min_compile_time_secs
+    try:
+        # set from outside: JAX reads the variables; nothing is set in code
+        assert env_mod.enable_compile_cache(
+            {"JAX_COMPILATION_CACHE_DIR": "/elsewhere",
+             "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "5"}
+        ) == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == min_s
+        # otherwise: the fixed directory inside the checkout, every program
+        want = os.path.join(repo_root, ".jax_cache")
+        assert env_mod.enable_compile_cache({}) == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert not jax.config.jax_enable_compilation_cache   # tests stay off
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", min_s)
 
 
 # ------------------------------------------------------------- CLI + bench

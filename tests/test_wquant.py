@@ -74,7 +74,8 @@ def test_consumer_leaves_stored_in_serving_mode():
     wd = qp["groups"][0]["p0"]["mlp"]["w_down"]
     assert is_qleaf(wd) and wd.mode == "fp8_e4m3"
     assert wd.q.dtype == jnp.float8_e4m3fn
-    assert wd.axes == ("layers", "dff", "fsdp")   # attached from specs
+    # attached from specs: a rotated consumer is stored by out-channel
+    assert wd.axes == ("layers", None, "qdout")
     emb = qp["emb"]
     assert is_qleaf(emb) and emb.mode == "int8"
 
