@@ -133,6 +133,7 @@ def _causal_mask(cfg, S: int, T: int):
 
 
 # ------------------------------------------------------------------ forward
+@jax.named_scope("attention")
 def apply_attention(
     cfg,
     p,
@@ -164,6 +165,7 @@ def apply_attention(
     return y
 
 
+@jax.named_scope("attention")
 def apply_cross_attention(cfg, p, x, kv: Tuple[jnp.ndarray, jnp.ndarray]):
     """Decoder->encoder cross attention; kv precomputed (B,T,KH,hd)."""
     B, S, _ = x.shape
@@ -193,6 +195,7 @@ def cross_kv(cfg, p, enc_out: jnp.ndarray):
     return _qk_spec(cfg, hd)(k), _v_spec(cfg, hd)(v)
 
 
+@jax.named_scope("attention")
 def decode_attention(
     cfg,
     p,

@@ -11,6 +11,12 @@ Entry points:
     lm_loss                      training forward + CE (+ MoE aux)
     lm_prefill                   forward returning logits + KV/state caches
     lm_decode_step               single-token decode on the caches
+
+Every compiled instruction carries a ``jax.named_scope`` path in its
+op_name metadata: ``embed``, ``layers`` (each group's scan), within it
+``attention`` and ``mlp``, then ``logits``. What falls in ``layers`` but
+in neither block is the scan's own work: slicing each layer's weights
+and caches out of the stacks and stacking the new caches back.
 """
 from __future__ import annotations
 
@@ -277,7 +283,8 @@ def _run_stack(cfg, groups_cfg, gparams, x, positions, enc_out,
             x = constrain(x, "batch", "seqpar", None)
             return x, (aux, cache)
 
-        x, (auxes, cache_stack) = jax.lax.scan(scan_body, x, gp)
+        with jax.named_scope("layers"):
+            x, (auxes, cache_stack) = jax.lax.scan(scan_body, x, gp)
         aux_total = aux_total + auxes.sum()
         caches.append(cache_stack if want_cache else None)
     return x, aux_total, caches
@@ -319,6 +326,7 @@ def lm_param_specs(cfg: ModelConfig):
     return specs
 
 
+@jax.named_scope("embed")
 def _embed_inputs(cfg, params, batch):
     """Build (x, positions) for the decoder stack from the input batch."""
     tokens = batch["tokens"]                       # (B, S_tok)
@@ -348,6 +356,7 @@ def _run_encoder(cfg, params, frames):
     return apply_norm(cfg, params["enc_norm"], x)
 
 
+@jax.named_scope("logits")
 def _logits(cfg, params, x):
     x = apply_norm(cfg, params["final_norm"], x)
     w = dequant_tree(params["emb"] if cfg.tie_embeddings else params["unemb"],
@@ -416,8 +425,9 @@ def lm_decode_step(cfg: ModelConfig, params, caches, tokens, cache_pos):
     per-slot positions -- the continuous-batching form, where every batch
     row is an independent request slot at its own depth (serving.engine).
     Returns (logits, new_caches)."""
-    emb = dequant_tree(params["emb"], jnp.dtype(cfg.dtype))
-    x = jnp.take(emb, tokens, axis=0)
+    with jax.named_scope("embed"):
+        emb = dequant_tree(params["emb"], jnp.dtype(cfg.dtype))
+        x = jnp.take(emb, tokens, axis=0)
     B = x.shape[0]
     if cfg.is_encdec:
         x = x + sinusoidal_positions(1, cfg.d_model).astype(x.dtype)[None]
@@ -448,6 +458,7 @@ def lm_decode_step(cfg: ModelConfig, params, caches, tokens, cache_pos):
                 new_c[f"p{j}"] = nc
             return x, new_c
 
-        x, new_stack = jax.lax.scan(body, x, (gp, cache_stack))
+        with jax.named_scope("layers"):
+            x, new_stack = jax.lax.scan(body, x, (gp, cache_stack))
         new_caches.append(new_stack)
     return _logits(cfg, params, x), new_caches

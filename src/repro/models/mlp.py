@@ -59,6 +59,7 @@ def mlp_specs(cfg):
     return p
 
 
+@jax.named_scope("mlp")
 def apply_mlp(cfg, p, x):
     qc = cfg.quant
     h = _act(cfg, x @ p["w_gate"]) * (x @ p["w_up"]) if cfg.act == "swiglu" \
@@ -108,6 +109,7 @@ def moe_specs(cfg):
     return p
 
 
+@jax.named_scope("mlp")
 def apply_moe(cfg, p, x):
     """x: (B,S,d). Top-k routing with capacity-factor dense dispatch."""
     B, S, d = x.shape

@@ -76,8 +76,29 @@ synthetic kernel raises, artificial step latency, NaN pokes into live
 KV rows. Zero-fault overhead is one attribute load + None check.
 
 Timing discipline: ``warmup()`` pays all three compiles on dummy inputs
-before any request is admitted, so reported per-token latencies are
+before any request is admitted, so reported decode-step times are
 steady-state (the same fix applied to ``serve.py``'s timed loop).
+
+Spans: each admission and decode step is a ``jax.profiler``
+annotation, recorded only while a profiler trace runs, on the clock of
+the device operations. A name is the text before any ``#``; the
+sub-spans of a step partition it up to a few microseconds of glue.
+
+  engine.admit            all of ``_admit`` (stats: rid, slot, prompt_len)
+    .prefill              pad the prompt, copy it over, dispatch prefill
+    .insert               dispatch the KV insert into the slot
+    .readback             block on the first token (and, guards on, on
+                          the prefill's ok flag, before the insert)
+  engine.decode           all of ``_decode_step`` (a step annotation,
+                          ``step_num`` = ``self.step``)
+    .dispatch             fault hooks, the host->device copies of tokens
+                          and positions, the decode call until it
+                          returns (retries and re-warms included)
+      .kv_check           ABFT only: the pre-step check, blocked on
+    .readback             block on the step and copy its tokens (and
+                          guard flags) to the host
+      .kv_roll            ABFT only: the post-step roll, blocked on
+    .bookkeep             the watchdog, then each slot's append/retire
 """
 from __future__ import annotations
 
@@ -89,6 +110,7 @@ from typing import Any, Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro import verify
 from repro.core import guards, wquant
@@ -263,7 +285,7 @@ class ServeEngine:
         self.completions: List[Completion] = []
         self._step_latencies_ms: List[float] = []
         self._occupancy: List[float] = []
-        self._decode_s = 0.0
+        self._serve_s = 0.0
         self._compile_s: Optional[float] = None
         self._idle_steps = 0
         self._qw_calls_baseline = wquant.QUANTIZE_WEIGHT_CALLS
@@ -385,52 +407,56 @@ class ServeEngine:
         return rejected
 
     def _admit(self, slot: int, req: Request) -> None:
-        padded = np.zeros((1, self.prefill_len), np.int32)
-        padded[0, :req.prompt_len] = req.tokens
-        t0 = time.perf_counter()
-        out = self._prefill(self.params, {"tokens": jnp.asarray(padded)},
-                            jnp.asarray(req.prompt_len, jnp.int32))
-        if self._guard or self._abft:
-            tok, ok, kv = out
-            if not bool(np.asarray(ok)[0]):
-                # poisoned prefill: never insert, never emit -- retire
-                # the freshly admitted slot as degraded on the spot.
-                # With ABFT on, attribute first: a stale weight checksum
-                # means silent corruption (sdc_detected), a clean one a
-                # transient numeric event (nan_guard).
-                reason = "nan_guard"
-                if self._abft and self._weights_corrupt():
-                    reason = "sdc_detected"
-                    self._note_sdc()
-                else:
-                    self.sched.counters["guard_trips"] += 1
-                    TRACE_COUNTS[("serving", "guard_trip")] += 1
-                self.completions.append(self.sched.retire(
-                    slot, reason, float(self.step)))
-                return
-        else:
-            tok, kv = out
-        self.caches = self._insert(self.caches, kv,
-                                   jnp.asarray(slot, jnp.int32))
-        tok_h = int(jax.block_until_ready(tok)[0])
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        TRACE_COUNTS[("serving", "prefill_insert")] += 1
-        self.sched.counters["prefill_inserts"] += 1
+        with TraceAnnotation("engine.admit", rid=req.rid, slot=slot,
+                             prompt_len=req.prompt_len):
+            with TraceAnnotation("engine.admit.prefill"):
+                padded = np.zeros((1, self.prefill_len), np.int32)
+                padded[0, :req.prompt_len] = req.tokens
+                out = self._prefill(
+                    self.params, {"tokens": jnp.asarray(padded)},
+                    jnp.asarray(req.prompt_len, jnp.int32))
+            if self._guard or self._abft:
+                tok, ok, kv = out
+                with TraceAnnotation("engine.admit.readback"):
+                    ok_h = bool(np.asarray(ok)[0])
+                if not ok_h:
+                    # poisoned prefill: never insert, never emit -- retire
+                    # the freshly admitted slot as degraded on the spot.
+                    # With ABFT on, attribute first: a stale weight
+                    # checksum means silent corruption (sdc_detected), a
+                    # clean one a transient numeric event (nan_guard).
+                    reason = "nan_guard"
+                    if self._abft and self._weights_corrupt():
+                        reason = "sdc_detected"
+                        self._note_sdc()
+                    else:
+                        self.sched.counters["guard_trips"] += 1
+                        TRACE_COUNTS[("serving", "guard_trip")] += 1
+                    self.completions.append(self.sched.retire(
+                        slot, reason, float(self.step)))
+                    return
+            else:
+                tok, kv = out
+            with TraceAnnotation("engine.admit.insert"):
+                self.caches = self._insert(self.caches, kv,
+                                           jnp.asarray(slot, jnp.int32))
+            with TraceAnnotation("engine.admit.readback"):
+                tok_h = int(jax.block_until_ready(tok)[0])
+            self.sched.counters["prefill_inserts"] += 1
 
-        st = self.sched.active[slot]
-        st.generated.append(tok_h)
-        st.latencies_ms.append(dt_ms)
-        self.tokens_h[slot, 0] = tok_h
-        self.positions_h[slot] = st.pos
-        if self._abft:
-            # rebase the slot's conservation state from the freshly
-            # inserted KV block (insert rewrites the block wholesale);
-            # blocked so this cache read cannot still be in flight when
-            # the next decode donates the buffers it walks
-            self.kv_sums = jax.block_until_ready(self._kv_reset(
-                self.kv_sums, self.caches, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(int(st.pos), jnp.int32)))
-        self._maybe_retire(slot, tok_h)
+            st = self.sched.active[slot]
+            st.generated.append(tok_h)
+            self.tokens_h[slot, 0] = tok_h
+            self.positions_h[slot] = st.pos
+            if self._abft:
+                # rebase the slot's conservation state from the freshly
+                # inserted KV block (insert rewrites the block wholesale);
+                # blocked so this cache read cannot still be in flight
+                # when the next decode donates the buffers it walks
+                self.kv_sums = jax.block_until_ready(self._kv_reset(
+                    self.kv_sums, self.caches, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(int(st.pos), jnp.int32)))
+            self._maybe_retire(slot, tok_h)
 
     def _maybe_retire(self, slot: int, last_tok: int) -> bool:
         st = self.sched.active[slot]
@@ -461,6 +487,7 @@ class ServeEngine:
         """Serve a whole arrival stream to completion; returns the
         completion records (also accumulated on ``self.completions``)."""
         self.warmup()
+        t0 = time.perf_counter()
         for req in requests:
             self.submit(req)
         while self.sched.has_work():
@@ -486,6 +513,7 @@ class ServeEngine:
                 self._idle_steps += 1
                 continue
             self._decode_step()
+        self._serve_s += time.perf_counter() - t0
         return self.completions
 
     def _inject_faults(self) -> None:
@@ -598,36 +626,50 @@ class ServeEngine:
             jnp.asarray(int(self.positions_h[slot]), jnp.int32)))
 
     def _decode_step(self) -> None:
-        t0 = time.perf_counter()
-        self._inject_faults()
-        kv_ok = cur = pos = None
-        if self._abft:
-            # pre-decode integrity gate on the exact caches the donated
-            # step is about to consume. block_until_ready serializes the
-            # read against the donated in-place reuse: an async-pending
-            # whole-cache read racing a donation is a runtime conflict,
-            # not a dataflow edge
-            pos = jnp.asarray(self.positions_h)
-            kv_ok, cur = self._kv_check(self.caches, pos, self.kv_sums)
-            jax.block_until_ready(cur)
-        out = self._decode_with_recovery()
-        if out is None:
-            self._fail_inflight("decode failed on every ladder rung")
-            return
-        new_tok, mid, self.caches = out
-        ok_h = np.asarray(mid) if (self._guard or self._abft) else None
-        kv_ok_h = None
-        if self._abft:
-            # roll the conservation state over the one row the step just
-            # wrote per slot (at the pre-step positions); blocked for the
-            # same reason as the pre-step check -- the NEXT step donates
-            # the cache buffers this read walks
-            self.kv_sums = jax.block_until_ready(
-                self._kv_roll(self.caches, pos, cur))
-            kv_ok_h = np.asarray(kv_ok)
-        new_tok_h = np.asarray(new_tok)           # blocks until ready
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        self._decode_s += dt_ms * 1e-3
+        with StepTraceAnnotation("engine.decode", step_num=self.step):
+            t0 = time.perf_counter()
+            kv_ok = cur = pos = None
+            with TraceAnnotation("engine.decode.dispatch"):
+                self._inject_faults()
+                if self._abft:
+                    # pre-decode integrity gate on the exact caches the
+                    # donated step is about to consume. block_until_ready
+                    # serializes the read against the donated in-place
+                    # reuse: an async-pending whole-cache read racing a
+                    # donation is a runtime conflict, not a dataflow edge
+                    with TraceAnnotation("engine.decode.kv_check"):
+                        pos = jnp.asarray(self.positions_h)
+                        kv_ok, cur = self._kv_check(self.caches, pos,
+                                                    self.kv_sums)
+                        jax.block_until_ready(cur)
+                out = self._decode_with_recovery()
+            if out is None:
+                self._fail_inflight("decode failed on every ladder rung")
+                return
+            new_tok, mid, self.caches = out
+            with TraceAnnotation("engine.decode.readback"):
+                ok_h = (np.asarray(mid) if (self._guard or self._abft)
+                        else None)
+                kv_ok_h = None
+                if self._abft:
+                    # roll the conservation state over the one row the
+                    # step just wrote per slot (at the pre-step
+                    # positions); blocked for the same reason as the
+                    # pre-step check -- the NEXT step donates the cache
+                    # buffers this read walks
+                    with TraceAnnotation("engine.decode.kv_roll"):
+                        self.kv_sums = jax.block_until_ready(
+                            self._kv_roll(self.caches, pos, cur))
+                    kv_ok_h = np.asarray(kv_ok)
+                new_tok_h = np.asarray(new_tok)       # blocks until ready
+            with TraceAnnotation("engine.decode.bookkeep"):
+                self._bookkeep((time.perf_counter() - t0) * 1e3,
+                               new_tok_h, ok_h, kv_ok_h)
+
+    def _bookkeep(self, dt_ms: float, new_tok_h, ok_h, kv_ok_h) -> None:
+        """After a decode step: the watchdog, then each slot's token
+        appended, or the slot retired where its guard or KV check
+        tripped or it finished."""
         self._step_latencies_ms.append(dt_ms)
         self._occupancy.append(self.sched.occupancy)
         self.step += 1
@@ -662,7 +704,6 @@ class ServeEngine:
                 continue
             tok = int(new_tok_h[slot, 0])
             st.generated.append(tok)
-            st.latencies_ms.append(dt_ms)
             st.pos += 1
             self.tokens_h[slot, 0] = tok
             self.positions_h[slot] = st.pos
@@ -729,12 +770,12 @@ class ServeEngine:
         }
 
     def summary(self) -> Dict[str, Any]:
-        # per-token latencies: decode-produced tokens only (index 0 is the
-        # prefill-produced first token, whose cost is the admission)
-        lat = np.asarray([ms for c in self.completions
-                          for ms in c.latencies_ms[1:]] or [0.0])
+        """Counters and rates of the engine's life so far. ``p50_token_ms``
+        and ``p99_token_ms`` are decode-step times (each step emits one
+        token per occupied slot); ``tokens_per_s`` is every generated
+        token over the wall time ``run()`` served, admissions included."""
+        steps = np.asarray(self._step_latencies_ms or [0.0])
         gen = sum(len(c.tokens) for c in self.completions)
-        gen_decode = sum(max(len(c.tokens) - 1, 0) for c in self.completions)
         by_status: Dict[str, int] = {}
         for c in self.completions:
             by_status[c.status] = by_status.get(c.status, 0) + 1
@@ -743,14 +784,13 @@ class ServeEngine:
             "generated_tokens": gen,
             "decode_steps": len(self._step_latencies_ms),
             "idle_steps": self._idle_steps,
-            "tokens_per_s": (gen_decode / self._decode_s
-                            if self._decode_s else 0.0),
+            "tokens_per_s": gen / self._serve_s if self._serve_s else 0.0,
             "occupancy": float(np.mean(self._occupancy)) if self._occupancy
             else 0.0,
-            "p50_token_ms": float(np.percentile(lat, 50)),
-            "p99_token_ms": float(np.percentile(lat, 99)),
+            "p50_token_ms": float(np.percentile(steps, 50)),
+            "p99_token_ms": float(np.percentile(steps, 99)),
             "compile_s": self._compile_s or 0.0,
-            "decode_s": self._decode_s,
+            "decode_s": sum(self._step_latencies_ms) * 1e-3,
             "decode_executables": self.decode_cache_size(),
             "quantize_weight_calls": self.quantize_weight_calls_during_serve(),
             "kv_cache_bytes": cache_bytes(self.cfg, self.sched.num_slots,

@@ -36,11 +36,14 @@ Every Completion carries ``status``: 'ok' (eos/length/cache_full),
 'timed_out' (deadline / deadline_shed), 'rejected' (queue_full), or
 'degraded' (nan_guard / engine_failed / shed_engine_failed).
 
-Observability: every transition bumps
-``kernels.registry.TRACE_COUNTS[("serving", <event>)]`` (admit / retire /
-prefill_insert / queue_full_stall / deadline_shed / queue_reject) plus
-per-scheduler counters, so tests and the engine's stats report read one
-shared ledger.
+Observability: every transition bumps a per-scheduler counter
+(``counters``: submitted / admitted / retired / prefill_inserts / shed /
+rejected / queue_full_stalls / ...), which the engine's ``summary()``
+reports. The events that mean load was turned away (queue_full_stall /
+deadline_shed / queue_reject) also tick the process-global
+``kernels.registry.TRACE_COUNTS[("serving", <event>)]``. Time is not
+kept here: the engine's profiler spans (``serving.engine``) time each
+admission and decode step.
 """
 from __future__ import annotations
 
@@ -79,7 +82,6 @@ class SlotState:
     max_new_tokens: int
     generated: List[int] = dataclasses.field(default_factory=list)
     admitted_step: int = 0
-    latencies_ms: List[float] = dataclasses.field(default_factory=list)
     deadline: Optional[float] = None
 
 
@@ -106,7 +108,6 @@ class Completion:
     finish_reason: str              # a STATUS_OF_REASON key
     admitted_step: int
     retired_step: int
-    latencies_ms: Tuple[float, ...]
     status: str = "ok"              # 'ok'|'timed_out'|'rejected'|'degraded'
 
 
@@ -169,7 +170,7 @@ class Scheduler:
         return Completion(
             rid=req.rid, prompt_len=req.prompt_len, tokens=(),
             finish_reason=reason, admitted_step=-1, retired_step=int(now),
-            latencies_ms=(), status=STATUS_OF_REASON[reason])
+            status=STATUS_OF_REASON[reason])
 
     # --------------------------------------------------------- admission
     def shed_expired(self, now: float,
@@ -211,7 +212,6 @@ class Scheduler:
             max_new_tokens=req.max_new_tokens, admitted_step=int(now),
             deadline=req.deadline)
         self.counters["admitted"] += 1
-        TRACE_COUNTS[("serving", "admit")] += 1
         return slot, req
 
     # -------------------------------------------------------- retirement
@@ -219,12 +219,10 @@ class Scheduler:
         st = self.active.pop(slot)
         self.free.append(slot)          # immediate LIFO reuse
         self.counters["retired"] += 1
-        TRACE_COUNTS[("serving", "retire")] += 1
         return Completion(
             rid=st.rid, prompt_len=st.prompt_len,
             tokens=tuple(st.generated), finish_reason=finish_reason,
             admitted_step=st.admitted_step, retired_step=int(now),
-            latencies_ms=tuple(st.latencies_ms),
             status=STATUS_OF_REASON.get(finish_reason, "degraded"))
 
     # ------------------------------------------------------------- state

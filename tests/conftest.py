@@ -41,3 +41,16 @@ def run_py_subprocess(code: str, devices: int = 8, timeout: int = 600):
 @pytest.fixture
 def subproc():
     return run_py_subprocess
+
+
+@pytest.fixture(autouse=True)
+def _trace_counts_end_with_the_test():
+    """What a test ticks in the process-global ``TRACE_COUNTS`` ends with
+    it: test files share worker processes, and the benchmark's harness
+    reads the fallback counters as absolute counts (bench/tests)."""
+    from repro.kernels.registry import TRACE_COUNTS
+
+    saved = dict(TRACE_COUNTS)
+    yield
+    TRACE_COUNTS.clear()
+    TRACE_COUNTS.update(saved)

@@ -344,3 +344,105 @@ def test_bench_serve_loop_smoke():
             "p99_ms"} <= set(ov)
     assert ov["rejected"] > 0 and ov["timed_out"] > 0
     assert ov["ok"] + ov["timed_out"] + ov["rejected"] + ov["degraded"] == 10
+
+
+# --------------------------------------------------------- spans + scopes
+def _engine_spans(xplane: str):
+    """The engine's host spans of one trace file, [name, start, end],
+    parents before their children."""
+    from jax.profiler import ProfileData
+
+    pd = ProfileData.from_file(xplane)
+    spans = [[e.name.partition("#")[0], e.start_ns, e.start_ns + e.duration_ns]
+             for plane in pd.planes if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("engine.")]
+    return sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def _children(spans, parent):
+    name, a, b = parent
+    return [s[0] for s in spans
+            if s[0].rpartition(".")[0] == name and a <= s[1] and s[2] <= b]
+
+
+def test_engine_spans_partition_each_step(quant_setup, tmp_path):
+    """Under a profiler, every admission holds its prefill, insert and
+    readback spans and every decode step one dispatch, readback and
+    bookkeep, in that order; the tokens are bitwise those of the same
+    engine run with no profiler, and ``summary()`` times decode steps and
+    rates tokens over the wall time served."""
+    import glob
+
+    from repro.serving import ServeEngine
+    from repro.serving.scheduler import Request
+
+    cfg, params, mesh = quant_setup
+    P, MAXLEN = 8, 32
+    prompts = _prompts(cfg, 2, P, seed=5)
+    reqs = [Request(rid=i, tokens=prompts[i], max_new_tokens=4 + i,
+                    arrival_time=float(i)) for i in range(2)]
+
+    def engine():
+        return ServeEngine(cfg, params, mesh, num_slots=2, max_len=MAXLEN,
+                           prefill_len=P)
+
+    traced = engine()
+    traced.warmup()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        got = {c.rid: c.tokens for c in traced.run(reqs)}
+    plain = engine()
+    want = {c.rid: c.tokens for c in plain.run(reqs)}
+    assert got == want
+
+    [xplane] = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = _engine_spans(xplane)
+    admits = [s for s in spans if s[0] == "engine.admit"]
+    decodes = [s for s in spans if s[0] == "engine.decode"]
+    s = traced.summary()
+    assert len(admits) == 2 and len(decodes) == s["decode_steps"] > 0
+    for a in admits:
+        assert _children(spans, a) == ["engine.admit.prefill",
+                                       "engine.admit.insert",
+                                       "engine.admit.readback"]
+    for d in decodes:
+        assert _children(spans, d) == ["engine.decode.dispatch",
+                                       "engine.decode.readback",
+                                       "engine.decode.bookkeep"]
+
+    steps = traced._step_latencies_ms
+    assert s["p50_token_ms"] == pytest.approx(float(np.percentile(steps, 50)))
+    assert s["decode_s"] == pytest.approx(sum(steps) * 1e-3)
+    # the wall time served holds the admissions too
+    assert 0 < s["tokens_per_s"] < s["generated_tokens"] / s["decode_s"]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_engine_programs_carry_model_scopes(quant_setup, program):
+    """The compiled steps name the model's scopes in their op_name
+    metadata: the layer scan's body holds attention and mlp; embedding and
+    logits sit outside it."""
+    import re
+
+    from repro.serving import ServeEngine
+
+    cfg, params, mesh = quant_setup
+    eng = ServeEngine(cfg, params, mesh, num_slots=2, max_len=32,
+                      prefill_len=8)
+    if program == "decode":
+        lowered = eng._decode.lower(eng.params, eng.caches,
+                                    jnp.asarray(eng.tokens_h),
+                                    jnp.asarray(eng.positions_h))
+    else:
+        lowered = eng._prefill.lower(
+            eng.params, {"tokens": jnp.zeros((1, 8), jnp.int32)},
+            jnp.asarray(1, jnp.int32))
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           lowered.compile().as_text()))
+    for scope in ("attention", "mlp"):
+        assert any(re.match(rf"jit\(\w+\)/layers/while/body/.*{scope}/", n)
+                   for n in names), scope
+    for scope in ("embed", "logits"):
+        assert any(re.match(rf"jit\(\w+\)/{scope}/", n) for n in names), scope
