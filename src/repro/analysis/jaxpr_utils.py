@@ -23,6 +23,7 @@ __all__ = [
     "count_primitive",
     "dots_by_region",
     "dots_outside_pallas",
+    "guarded_eqns",
     "iter_eqns",
     "kernel_jaxpr",
     "kernel_jaxprs",
@@ -91,19 +92,26 @@ def kernel_jaxpr(jaxpr) -> Jaxpr:
     return found[0]
 
 
-def dots_by_region(kjaxpr) -> Tuple[int, int]:
-    """(top-level dot_general count, dot_general count inside cond
-    branches) of a kernel jaxpr -- the structural signature of the
-    rotate-once schedule: the transform's pass matmuls live under the
-    ``j == 0`` cond, the contraction outside it."""
-    kjaxpr = as_jaxpr(kjaxpr)
-    top = sum(1 for e in kjaxpr.eqns if e.primitive.name == "dot_general")
-    in_cond = 0
-    for e in kjaxpr.eqns:
+def guarded_eqns(kjaxpr) -> Iterator:
+    """Every eqn under the top-level conds of a kernel jaxpr (the
+    ``pl.when`` regions), through the loops nested in them: a loop body
+    appears once, however many times it runs."""
+    for e in as_jaxpr(kjaxpr).eqns:
         if e.primitive.name == "cond":
             for br in e.params["branches"]:
-                in_cond += sum(1 for q in as_jaxpr(br).eqns
-                               if q.primitive.name == "dot_general")
+                yield from iter_eqns(br)
+
+
+def dots_by_region(kjaxpr) -> Tuple[int, int]:
+    """(top-level dot_general count, dot_general count under cond
+    branches) of a kernel jaxpr -- the structural signature of the
+    rotate-once schedule: the transform's pass matmuls live under the
+    ``j == 0`` cond (in the chunk loop's body where the row block is
+    rotated in chunks), the contraction outside it."""
+    kjaxpr = as_jaxpr(kjaxpr)
+    top = sum(1 for e in kjaxpr.eqns if e.primitive.name == "dot_general")
+    in_cond = sum(1 for e in guarded_eqns(kjaxpr)
+                  if e.primitive.name == "dot_general")
     return top, in_cond
 
 

@@ -244,24 +244,19 @@ class DtypeFlow(Rule):
             cd = jnp.dtype(site.plan.compute_dtype)
             if cd.itemsize == 2:
                 for kj in ju.kernel_jaxprs(site.jaxpr):
-                    for e in ju.as_jaxpr(kj).eqns:
-                        if e.primitive.name != "cond":
+                    for q in ju.guarded_eqns(kj):
+                        if q.primitive.name != "dot_general":
                             continue
-                        for br in e.params["branches"]:
-                            for q in ju.as_jaxpr(br).eqns:
-                                if q.primitive.name != "dot_general":
-                                    continue
-                                dts = {q.invars[0].aval.dtype,
-                                       q.invars[1].aval.dtype}
-                                wide = [str(d) for d in dts
-                                        if jnp.dtype(d).itemsize > 2]
-                                if wide:
-                                    out.append(self._v(
-                                        site, "transform pass matmul has "
-                                        f"{wide} operand(s) under a "
-                                        f"{cd.name} compute plan -- "
-                                        "silent f32 upcast of the pass "
-                                        "compute"))
+                        dts = {q.invars[0].aval.dtype,
+                               q.invars[1].aval.dtype}
+                        wide = [str(d) for d in dts
+                                if jnp.dtype(d).itemsize > 2]
+                        if wide:
+                            out.append(self._v(
+                                site, "transform pass matmul has "
+                                f"{wide} operand(s) under a "
+                                f"{cd.name} compute plan -- "
+                                "silent f32 upcast of the pass compute"))
         if site.kind == "serving" and site.cache_leaves:
             cache_shapes = {tuple(s) for s, _ in site.cache_leaves}
             io = jnp.dtype(site.io_dtype)

@@ -20,8 +20,8 @@ import dataclasses
 import os
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["Site", "kernel_sites", "model_sites", "serving_sites",
-           "default_sites", "traced"]
+__all__ = ["Site", "kernel_sites", "w_down_sites", "model_sites",
+           "serving_sites", "default_sites", "traced"]
 
 _SHIM_KEYS = (
     ("deprecated", "kernels.ops.hadamard"),
@@ -166,6 +166,46 @@ def kernel_sites(config_name: str, schedule: str = "rotate_once",
     return sites
 
 
+def w_down_sites(schedule: str = "rotate_once",
+                 config_name: str = "phi4_mini_3_8b") -> List[Site]:
+    """The fp8 w_down dispatch of ``config_name`` at its published
+    widths (n = d_ff, d = d_model; Phi-4-mini: 8192 x 3072, bf16 io),
+    traced on abstract shapes at a decode batch (m = 32) and a prefill
+    bucket (m = 1024): the sizes at which the tile decision has to give
+    the row block room, charged and checked as it runs there."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.configs import get_config
+    from repro.core.api import QuantEpilogue, plan_for
+    from repro.kernels.quant_dot import pallas_quant_dot, quant_dot_blocks
+
+    cfg = get_config(config_name)
+    n, d, mode = cfg.d_ff, cfg.d_model, "fp8_e4m3"
+    io = jnp.dtype(cfg.dtype)
+    plan = plan_for(n, dtype=io, backend="pallas",
+                    epilogue=QuantEpilogue(mode))
+    wq = jax.ShapeDtypeStruct((n, d), jnp.float8_e4m3fn)
+    sw = jax.ShapeDtypeStruct((1, d), jnp.float32)
+    ctx = (_stream_interpret_forced() if schedule == "streamed"
+           else contextlib.nullcontext())
+    sites = []
+    with ctx:
+        for m in (32, 1024):
+            decision = quant_dot_blocks(n, d, m, io, plan.compute_dtype,
+                                        mode, schedule=schedule)
+            jaxpr, qw, shim = traced(
+                lambda a, q, s: pallas_quant_dot(a, q, s, plan, True,
+                                                 schedule),
+                jax.ShapeDtypeStruct((m, n), io), wq, sw)
+            sites.append(Site(
+                name=f"w_down[{config_name}/{schedule}/m={m}]",
+                kind="kernel", jaxpr=jaxpr, schedule=schedule, plan=plan,
+                decision=decision, io_dtype=io, qw_calls=qw,
+                shim_calls=shim))
+    return sites
+
+
 def model_sites(config_name: str) -> List[Site]:
     """The bound-spec model forward: the scaled config's MLP with a
     fusable pow-2 down-projection, int8 pallas quantization -- the
@@ -260,6 +300,7 @@ def default_sites(config_name: str, schedule: str = "rotate_once",
     sites = kernel_sites(config_name, schedule)
     if abft:
         sites += kernel_sites(config_name, schedule, abft=True)
+    sites += w_down_sites(schedule)
     sites += model_sites(config_name)
     if serving:
         sites += serving_sites(config_name)

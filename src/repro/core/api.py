@@ -466,15 +466,14 @@ def hadamard(
 def _qd_fusable(plan: HadamardPlan) -> bool:
     """Can the rotate+quantize+dot run as the backend's single kernel?
     Mirrors ``_fusable`` plus the backend must host a ``quant_dot`` and
-    the minimal (p, 128) weight tile must fit the kernel's VMEM budget
-    (fp8 operands cost ``_FP8_OPERAND_BYTES`` a element in VMEM: storage,
-    the f32 conversion and the exact bf16 embedding). An oversize plan takes the unfused path instead of
+    the minimal (p, 128) weight tile, charged as ``quant_dot_blocks``
+    charges it (``_weight_tile_bytes``), must fit the kernel's VMEM
+    budget. An oversize plan takes the unfused path instead of
     launching an over-budget kernel -- warned once per process and
     counted in ``TRACE_COUNTS[("quant_dot", "vmem_unfused")]``."""
-    from repro.kernels.quant_dot import _FP8_OPERAND_BYTES
+    from repro.kernels.quant_dot import _weight_tile_bytes
 
     be = get_backend(plan.backend)
-    wb = 1 if QSPECS[plan.epilogue.mode][2] else _FP8_OPERAND_BYTES
     kernel_ok = (
         not plan.grouped
         and plan.p > 1
@@ -482,7 +481,8 @@ def _qd_fusable(plan: HadamardPlan) -> bool:
         and getattr(be, "quant_dot", None) is not None
         and be.supports(plan.p)
     )
-    if kernel_ok and plan.p * 128 * wb > registry._VMEM_BUDGET_BYTES:
+    if kernel_ok and (_weight_tile_bytes(plan.p, 128, plan.epilogue.mode)
+                      > registry._VMEM_BUDGET_BYTES):
         registry.warn_once(
             ("quant_dot", "vmem_unfused"),
             f"the n={plan.p} {plan.epilogue.mode} quant_dot weight tile "

@@ -38,6 +38,19 @@ row. The default schedule here is **rotate-once**:
     sequential within each i), so each row is transformed exactly once
     regardless of d.
 
+Every row block fetches its (n, block_n) weight tiles afresh, and on a
+chip without fp8 arithmetic (v5e) converts each fp8 tile to its bf16
+operand afresh: that cost is paid once per row block, whatever the rows
+hold, so ``quant_dot_blocks`` sizes the row block first. To leave the
+rows room, the j == 0 stage rotates the block ``rotate_rows`` rows at a
+time into the scratch (``_rotate_into``; the rotation and the quantizer
+are row-independent, so the scratch is bitwise what a whole-block pass
+writes), and the fp8 tile is converted into a bf16 VMEM scratch
+``convert_rows`` K-rows at a time before the one dot over the whole K
+(``_weight_operand``; the contraction and its f32 accumulation order are
+unchanged). The transform's temporaries and the conversion's f32 then
+cost one chunk, not the block or the tile.
+
 The PR-3 ``revisit`` schedule is kept selectable (``schedule="revisit"``
 or ``REPRO_QUANT_DOT_SCHEDULE=revisit``) as the A/B baseline for the
 transform-amortization benchmark; both schedules are bitwise identical
@@ -68,9 +81,9 @@ Slot parity resets at each new (expert, row block) pair for free: the
 slot index is ``j % 2`` of the RESTARTED j loop and the j == 0 warm-up
 re-primes slot 0, while the ``j + 1 < nj`` guard drains all in-flight
 copies before the row block ends -- no DMA crosses a row-block (or
-expert) boundary. ``quant_dot_blocks`` charges the second weight-tile
-slot and the scale ring when sizing streamed blocks, so streamed block
-sizes never oversubscribe VMEM.
+expert) boundary. ``quant_dot_blocks`` charges both weight-tile slots
+and the scale ring when sizing streamed blocks, so streamed block sizes
+never oversubscribe VMEM.
 
 Interpret mode has no real DMA engine (the XLA interpreter simulates
 ``make_async_copy`` synchronously), so off-TPU dispatch of
@@ -96,6 +109,7 @@ it so fused and unfused paths agree bit-for-bit in the contraction.
 from __future__ import annotations
 
 import functools
+import logging
 import os
 
 import jax
@@ -124,23 +138,26 @@ __all__ = ["pallas_quant_dot", "pallas_quant_dot_experts", "xla_quant_dot",
 
 _CONTRACT = (((1,), (0,)), ((), ()))  # plain (m, k) @ (k, n)
 
+_LOG = logging.getLogger(__name__)
+
 # Largest contraction dim whose worst-case int8 x int8 row sum stays in
 # int32: 127 * 127 * 2^17 ~= 2.11e9 < 2^31 - 1 (2^18 would wrap). Only
 # the above-cap XLA fallback can exceed this -- the kernel caps at 2^15.
 _INT32_SAFE_K = 1 << 17
 
-# fp8 weight-tile bytes/element inside the kernel: the 1-byte storage
-# grid, the f32 value its conversion to bf16 goes through on a chip
-# without fp8 arithmetic (v5e), and the exact bf16 embedding the dot
-# runs in. Charged at 3 (no f32), an n=2048 tile of 1024 out-channels
-# overflowed the 16 MiB scoped VMEM at any row block.
-_FP8_OPERAND_BYTES = 7
+# f32 bytes of the temporary that one K-slice of the fp8 weight tile goes
+# through on its way to the bf16 operand scratch (v5e has no fp8
+# arithmetic, so the conversion passes through f32). Converting the whole
+# (n, block_n) tile at once cost 4 bytes an element on top of the
+# storage and bf16 copies, which left room for 16 rows at n=8192.
+_CONVERT_SLICE_BYTES = 64 * 1024
 
-# f32 bytes/element of the row block that the j == 0 rotate+quantize
-# holds at once: the pass output, its minor-axis transpose and the
-# quantizer's quotient. Mosaic allocates them for the whole kernel, so
-# leaving them out let fp8 prefill tiles past the compiler's 16 MiB
-# scoped-VMEM limit on v5e.
+# f32 bytes/element of the rows that the j == 0 rotate+quantize holds at
+# once: the pass output, its minor-axis transpose and the quantizer's
+# quotient. Mosaic allocates them for the whole kernel, so leaving them
+# out let fp8 prefill tiles past the compiler's 16 MiB scoped-VMEM limit
+# on v5e. The stage runs ``BlockDecision.rotate_rows`` rows at a time, so
+# they are charged for one such chunk, not for the whole row block.
 _ROTATE_TEMP_BYTES = 12
 
 # Below this n the a = n/128 sublane rows of a row tile are too few to
@@ -220,30 +237,76 @@ def _operand_bytes(mode: str) -> int:
     return 1 if QSPECS[mode][2] else 2
 
 
+def _rotate_rows(bm: int, in_b: int, mode: str, schedule: str) -> int:
+    """Rows the j == 0 stage rotates and quantizes at a time: one sublane
+    tile of the narrower of the input and scratch-operand dtypes (16 rows
+    for bf16, 32 for int8), so every chunk's loads and stores stay
+    tile-aligned. The whole row block where it is no multiple of that
+    tile, and under ``revisit``, whose body rotates its block whole on
+    every step."""
+    tile = 32 // min(in_b, _operand_bytes(mode))
+    if schedule == "revisit" or bm % tile:
+        return bm
+    return tile
+
+
+def _convert_rows(n: int, bn: int, mode: str, schedule: str) -> int:
+    """K rows of the fp8 weight tile converted to bf16 at a time: the
+    largest power of two (at least one 32-row fp8 sublane tile, at most
+    n) whose f32 temporary fits ``_CONVERT_SLICE_BYTES``. 0 for int8,
+    whose tile is contracted as it is stored; n under ``revisit``, whose
+    body converts the tile whole."""
+    if QSPECS[mode][2]:
+        return 0
+    if schedule == "revisit":
+        return n
+    ks = 32
+    while ks * 2 * bn * 4 <= _CONVERT_SLICE_BYTES:
+        ks *= 2
+    return ks if ks <= n and n % ks == 0 else n
+
+
+def _weight_tile_bytes(n: int, bn: int, mode: str,
+                       schedule: str = "rotate_once") -> int:
+    """VMEM of one (n, bn) weight tile inside the kernel: two slots of the
+    1-byte storage grid (the BlockSpec pipeline's double buffer, or the
+    streamed ring) and, for fp8, the bf16 operand scratch plus one
+    K-slice's f32 conversion temporary. ``core.api._qd_fusable`` gates
+    fusion on this charge at bn=128."""
+    ks = _convert_rows(n, bn, mode, schedule)
+    return 2 * n * bn + (n * bn * 2 + ks * bn * 4 if ks else 0)
+
+
 class BlockDecision(tuple):
     """The ``(block_m, block_n)`` tile decision, as a tuple subclass so
     every historical ``bm, bn = quant_dot_blocks(...)`` unpack (and
     ``== (bm, bn)`` comparison) keeps working, carrying the metadata the
-    benches log alongside the tiles:
+    kernels and benches read alongside the tiles:
 
     * ``schedule``   -- the grid schedule the sizes were charged for
-      (the streamed DMA ring costs a second weight-tile slot + a scale
-      ring, so its block sizes can be narrower);
+      (the streamed DMA ring costs a second scale slot, so its block
+      sizes can be narrower);
     * ``vmem_bytes`` -- the estimated VMEM high-water mark of the chosen
       tiles under that schedule: within the kernel budget, except where
       the smallest tile (one sublane group of rows x 128 out-channels)
-      exceeds it and is returned anyway -- fp8 at n=8192 is charged
-      9.7 MiB, which Mosaic compiles within its 16 MiB limit.
+      exceeds it and is returned anyway (int8 at the n=2^15 kernel cap);
+    * ``rotate_rows`` -- rows the j == 0 stage rotates at a time;
+    * ``convert_rows`` -- K rows of an fp8 weight tile converted to its
+      bf16 operand at a time (0: int8, no conversion).
     """
 
     schedule: str
     vmem_bytes: int
+    rotate_rows: int
+    convert_rows: int
 
     def __new__(cls, block_m: int, block_n: int, schedule: str,
-                vmem_bytes: int):
+                vmem_bytes: int, rotate_rows=None, convert_rows: int = 0):
         self = super().__new__(cls, (block_m, block_n))
         self.schedule = schedule
         self.vmem_bytes = vmem_bytes
+        self.rotate_rows = block_m if rotate_rows is None else rotate_rows
+        self.convert_rows = convert_rows
         return self
 
     @property
@@ -256,7 +319,9 @@ class BlockDecision(tuple):
 
     def __repr__(self):
         return (f"BlockDecision(block_m={self[0]}, block_n={self[1]}, "
-                f"schedule={self.schedule!r}, vmem_bytes={self.vmem_bytes})")
+                f"schedule={self.schedule!r}, vmem_bytes={self.vmem_bytes}, "
+                f"rotate_rows={self.rotate_rows}, "
+                f"convert_rows={self.convert_rows})")
 
 
 def quant_dot_blocks(n: int, d: int, m: int, dtype, compute_dtype,
@@ -264,29 +329,29 @@ def quant_dot_blocks(n: int, d: int, m: int, dtype, compute_dtype,
                      schedule: str = "rotate_once",
                      abft: bool = False) -> BlockDecision:
     """The tile decision for the fused kernel, charging every VMEM
-    resident of the requested schedule: the input tile + compute-dtype
-    working copy per row, the SCRATCH dot-operand tile (int8 / bf16) + the
-    per-row f32 scale that live across the j loop, the weight tile(s),
-    the (block_m, block_n) output tile, and the per-out-channel scales.
+    resident of the requested schedule. Per row of the block: the input
+    tile, the SCRATCH dot-operand row (int8 / bf16) and its f32 scale,
+    which live across the j loop, and the output row. Once: the
+    rotation's compute-dtype copy and f32 temporaries for one chunk of
+    ``rotate_rows`` rows, the weight tile (``_weight_tile_bytes``: two
+    storage slots, and for fp8 the bf16 operand and one K-slice's f32
+    conversion) and the per-out-channel scales.
 
-    ``schedule="streamed"`` charges the DMA ring on top: a SECOND
-    (n, block_n) weight-tile slot in the storage dtype plus the two-slot
-    f32 scale ring (the DMA semaphores are register-file residents --
-    free as far as this budget is concerned), so streamed block sizes
-    never oversubscribe VMEM. The chosen schedule and the estimated VMEM
-    high-water mark ride along on the returned :class:`BlockDecision`
-    (a (block_m, block_n) tuple) so benches can record the decision.
+    Every row block fetches (and for fp8 converts) the whole weight once,
+    so rows come first: ``block_m`` is the largest sublane-aligned row
+    count up to min(256, m) that fits beside the narrowest (n, 128)
+    weight tile, then ``block_n`` widens (up to 1024) with what those
+    rows leave. ``schedule="streamed"`` charges the two-slot f32 scale
+    ring on top (the DMA semaphores are register-file residents -- free
+    as far as this budget is concerned), so streamed block sizes never
+    oversubscribe VMEM. The chosen schedule, the estimated VMEM
+    high-water mark and the two chunk sizes ride along on the returned
+    :class:`BlockDecision` (a (block_m, block_n) tuple).
 
     A user-pinned ``block_m`` (``plan.block_m``) is honored BEFORE any
-    sizing decision, so the weight-tile / ``block_n`` tradeoff is
-    computed against the row count that will actually run -- not against
-    a heuristic ``bm`` that the pin then overrides. ``block_n`` pins the
-    out-channel tile the same way (benchmarks use it to hold the revisit
-    count fixed across schedules).
-
-    Because the rotate-once schedule makes weight-tile revisits free of
-    transform recompute, ``block_n`` is allowed up to 1024 (PR 3 capped
-    it at 512 to keep the per-revisit transform bill bounded).
+    sizing decision, so ``block_n`` is sized against the row count that
+    will actually run. ``block_n`` pins the out-channel tile the same way
+    (benchmarks use it to hold the revisit count fixed across schedules).
 
     ``abft=True`` charges the checksum-verified kernel variant: the
     (1, n) f32 column-checksum input tile (block-constant across the
@@ -298,50 +363,40 @@ def quant_dot_blocks(n: int, d: int, m: int, dtype, compute_dtype,
     identity across decisions)."""
     in_b = jnp.dtype(dtype).itemsize
     cb = jnp.dtype(compute_dtype).itemsize
-    is_int = QSPECS[mode][2]
-    qb = _operand_bytes(mode)       # scratch operand bytes/element
-    wb = 1 if is_int else _FP8_OPERAND_BYTES
-    swb = 4                         # f32 per-out-channel scale tile
-    if schedule == "streamed":
-        # the ring's second weight slot holds the 1-byte STORAGE grid for
-        # both paths (the fp8 bf16-embedding temporary is made per
-        # contraction, never per slot), and the scale tile doubles
-        wb += 1
-        swb *= 2
-    # per-row residents independent of bn: input tile + compute copy +
-    # scratch operand + the rotation's f32 temporaries + f32 scratch scale
-    row_fixed = (n * (in_b + cb + qb) + 4
-                 + _ROTATE_TEMP_BYTES * max(n, _ROTATE_TEMP_MIN_N))
+    qb = _operand_bytes(mode)
+    swb = 8 if schedule == "streamed" else 4   # f32 scale tile (ring: 2)
+    row = n * (in_b + qb) + 4
     fixed = 0
     if abft:
-        row_fixed += 12             # chk + acc scratch + residual out tile
+        row += 12                   # chk + acc scratch + residual out tile
         fixed = n * 4               # (1, n) f32 column-checksum input
+    rot_row = n * cb + _ROTATE_TEMP_BYTES * max(n, _ROTATE_TEMP_MIN_N)
 
     def vmem(bm_, bn_):
-        return fixed + bm_ * row_fixed + bn_ * (n * wb + bm_ * in_b + swb)
+        return (fixed + bm_ * (row + bn_ * in_b)
+                + _rotate_rows(bm_, in_b, mode, schedule) * rot_row
+                + _weight_tile_bytes(n, bn_, mode, schedule) + bn_ * swb)
 
-    # bn always steps in 128-lane multiples so the BlockSpec last dim
-    # stays MXU-tiled
-    bn = min(1024, -(-d // 128) * 128) if block_n is None else block_n
-    if block_m is not None:
-        if block_n is None:
-            # pinned rows: the weight/output/sw tiles get everything the
-            # rows leave
-            avail = _VMEM_BUDGET_BYTES - fixed - block_m * row_fixed
-            while bn > 128 and bn * (n * wb + block_m * in_b + swb) > avail:
-                bn -= 128
-        return BlockDecision(block_m, bn, schedule, vmem(block_m, bn))
-    if block_n is None:
-        # joint sizing: cap the weight tile at half the budget (oversizing
-        # it starves block_m), then size the rows from the remainder
-        while n * bn * wb > _VMEM_BUDGET_BYTES // 2 and bn > 128:
-            bn -= 128
-    per_row = row_fixed + bn * in_b
-    bm = max(8, (_VMEM_BUDGET_BYTES - fixed - bn * (n * wb + swb)) // per_row)
-    bm = min(bm, 256, m)
-    sub = 16 if in_b == 2 else 8
-    bm = max(sub, (bm // sub) * sub)
-    return BlockDecision(bm, bn, schedule, vmem(bm, bn))
+    def widest(sizes, fits):
+        return next((s for s in sizes if fits(s)), sizes[-1])
+
+    bm = block_m
+    if bm is None:
+        sub = 16 if in_b == 2 else 8
+        tile = 32 // min(in_b, qb)
+        top = min(256, -(-max(m, 1) // sub) * sub)
+        rows = [b for b in range(top, 0, -sub) if b % tile == 0 or b < tile]
+        probe = 128 if block_n is None else block_n
+        bm = widest(rows, lambda b: vmem(b, probe) <= _VMEM_BUDGET_BYTES)
+    bn = block_n
+    if bn is None:
+        # bn always steps in 128-lane multiples so the BlockSpec last dim
+        # stays MXU-tiled
+        cols = list(range(min(1024, -(-d // 128) * 128), 0, -128))
+        bn = widest(cols, lambda c: vmem(bm, c) <= _VMEM_BUDGET_BYTES)
+    return BlockDecision(bm, bn, schedule, vmem(bm, bn),
+                         _rotate_rows(bm, in_b, mode, schedule),
+                         _convert_rows(n, bn, mode, schedule))
 
 
 def _rotate_quantize_block(x, mats_ref, *, n: int, mode: str,
@@ -357,9 +412,66 @@ def _rotate_quantize_block(x, mats_ref, *, n: int, mode: str,
     return _quantize_rows(y.astype(jnp.float32), mode)
 
 
+def _rotate_into(x_ref, mats_ref, q_ref, s_ref, *, n: int, mode: str,
+                 compute_dtype, rows: int, chk=None):
+    """The j == 0 stage of the rotate-once kernels: rotate and per-token
+    quantize the (block_m, n) row block ``rows`` rows at a time, storing
+    each chunk's dot-operand form in ``q_ref`` and its f32 scales in
+    ``s_ref``. The rotation and the quantizer are row-independent, so the
+    scratch is bitwise what one whole-block pass writes, while the
+    compute-dtype copy and the f32 temporaries exist for one chunk only.
+    ``chk=(chk_ref, cw)`` also stores each chunk's ABFT checksum column
+    against the (1, n) f32 column checksum ``cw``."""
+    bm = q_ref.shape[0]
+
+    def chunk(sl):
+        q, s = _rotate_quantize_block(x_ref[sl, :], mats_ref, n=n,
+                                      mode=mode, compute_dtype=compute_dtype)
+        op = _operand_from_q(q, mode)
+        q_ref[sl, :] = op
+        s_ref[sl, :] = s
+        if chk is not None:
+            chk_ref, cw = chk
+            chk_ref[sl, :] = _abft_check_col(op, cw)
+
+    if rows == bm:
+        chunk(slice(None))
+        return
+
+    def body(c, carry):
+        chunk(pl.ds(pl.multiple_of(c * rows, rows), rows))
+        return carry
+
+    jax.lax.fori_loop(0, bm // rows, body, 0)
+
+
+def _weight_operand(w_ref, wb_ref, mode: str, ks: int):
+    """The contraction's weight operand from a storage-dtype (n, block_n)
+    tile ref. int8 is contracted as it is stored (``ks == 0``). fp8 is
+    converted to its exact bf16 embedding into the ``wb_ref`` scratch
+    ``ks`` K-rows at a time, so the f32 that v5e's conversion goes through
+    covers one slice, not the tile; the one dot that follows still runs
+    over the whole K, so its f32 accumulation order is unchanged."""
+    if not ks:
+        return w_ref[...]
+    n = w_ref.shape[0]
+
+    def body(c, carry):
+        k = pl.ds(pl.multiple_of(c * ks, ks), ks)
+        wb_ref[k, :] = w_ref[k, :].astype(jnp.bfloat16)
+        return carry
+
+    if ks == n:
+        wb_ref[...] = w_ref[...].astype(jnp.bfloat16)
+    else:
+        jax.lax.fori_loop(0, n // ks, body, 0)
+    return wb_ref[...]
+
+
 def _quant_dot_kernel_rotate_once(x_ref, mats_ref, wq_ref, sw_ref, o_ref,
-                                  q_ref, s_ref, *, n: int, mode: str,
-                                  compute_dtype):
+                                  q_ref, s_ref, wb_ref=None, *, n: int,
+                                  mode: str, compute_dtype, rows: int,
+                                  ks: int):
     """Rotate-once grid step. The out-channel axis j (innermost,
     sequential) revisits the same row block i with consecutive weight
     tiles; the rotation + per-token quantization run ONLY at j == 0 and
@@ -370,16 +482,16 @@ def _quant_dot_kernel_rotate_once(x_ref, mats_ref, wq_ref, sw_ref, o_ref,
     across grid steps and j is sequential within each i, so the j == 0
     write is visible to every later j of that row block (and rows blocks
     may still run in parallel across cores: each partition owns its own
-    scratch and walks its own j loop in order)."""
+    scratch and walks its own j loop in order). ``wb_ref`` is the fp8
+    tile's bf16 operand scratch (``_weight_operand``); int8 has none."""
 
     @pl.when(pl.program_id(1) == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[...], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        q_ref[...] = _operand_from_q(q, mode)
-        s_ref[...] = s
+        _rotate_into(x_ref, mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows)
 
-    acc = _operand_dot(q_ref[...], wq_ref[...], mode)
+    acc = _operand_dot(q_ref[...], _weight_operand(wq_ref, wb_ref, mode, ks),
+                       mode)
     o_ref[...] = (acc * s_ref[...] * sw_ref[...]).astype(o_ref.dtype)
 
 
@@ -421,8 +533,9 @@ def _ring_dmas(make_w, make_s, j, nj: int):
 
 def _quant_dot_kernel_streamed(x_ref, mats_ref, wq_hbm, sw_hbm, o_ref,
                                q_ref, s_ref, w_ring, sw_ring, w_sem, s_sem,
-                               *, n: int, mode: str, compute_dtype,
-                               bn: int, nj: int):
+                               wb_ref=None, *, n: int, mode: str,
+                               compute_dtype, rows: int, ks: int, bn: int,
+                               nj: int):
     """Streamed grid step: rotate-once structure + a manual two-slot VMEM
     ring over the weight/scale operands (``wq_hbm``/``sw_hbm`` are
     UNBLOCKED ANY-memory-space refs; the implicit BlockSpec weight
@@ -448,13 +561,13 @@ def _quant_dot_kernel_streamed(x_ref, mats_ref, wq_hbm, sw_hbm, o_ref,
 
     @pl.when(j == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[...], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        q_ref[...] = _operand_from_q(q, mode)
-        s_ref[...] = s
+        _rotate_into(x_ref, mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows)
 
     slot = finish()
-    acc = _operand_dot(q_ref[...], w_ring[slot], mode)
+    acc = _operand_dot(q_ref[...],
+                       _weight_operand(w_ring.at[slot], wb_ref, mode, ks),
+                       mode)
     o_ref[...] = (acc * s_ref[...] * sw_ring[slot]).astype(o_ref.dtype)
 
 
@@ -485,8 +598,9 @@ def _abft_check_col(op, cw):
 
 def _quant_dot_kernel_rotate_once_abft(x_ref, mats_ref, wq_ref, sw_ref,
                                        cw_ref, o_ref, r_ref, q_ref, s_ref,
-                                       chk_ref, acc_ref, *, n: int, mode: str,
-                                       compute_dtype):
+                                       chk_ref, acc_ref, wb_ref=None, *,
+                                       n: int, mode: str, compute_dtype,
+                                       rows: int, ks: int):
     """The rotate-once grid step with the ABFT checksum column riding
     INSIDE the same pallas_call (fusion contract intact). j == 0
     additionally stashes the activation checksum ``chk`` (one extra
@@ -500,15 +614,13 @@ def _quant_dot_kernel_rotate_once_abft(x_ref, mats_ref, wq_ref, sw_ref,
 
     @pl.when(pl.program_id(1) == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[...], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        op = _operand_from_q(q, mode)
-        q_ref[...] = op
-        s_ref[...] = s
-        chk_ref[...] = _abft_check_col(op, cw_ref[...])
+        _rotate_into(x_ref, mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows,
+                     chk=(chk_ref, cw_ref[...]))
         acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
-    acc = _operand_dot(q_ref[...], wq_ref[...], mode)
+    acc = _operand_dot(q_ref[...], _weight_operand(wq_ref, wb_ref, mode, ks),
+                       mode)
     contrib = acc * s_ref[...] * sw_ref[...]
     o_ref[...] = contrib.astype(o_ref.dtype)
     acc_ref[...] += jnp.sum(contrib, axis=-1, keepdims=True)
@@ -518,7 +630,8 @@ def _quant_dot_kernel_rotate_once_abft(x_ref, mats_ref, wq_ref, sw_ref,
 def _quant_dot_kernel_streamed_abft(x_ref, mats_ref, wq_hbm, sw_hbm, cw_ref,
                                     o_ref, r_ref, q_ref, s_ref, chk_ref,
                                     acc_ref, w_ring, sw_ring, w_sem, s_sem,
-                                    *, n: int, mode: str, compute_dtype,
+                                    wb_ref=None, *, n: int, mode: str,
+                                    compute_dtype, rows: int, ks: int,
                                     bn: int, nj: int):
     """Streamed grid step + ABFT. The column checksum ``cw_ref`` rides as
     a plain VMEM BlockSpec input OUTSIDE the DMA ring on purpose: the
@@ -542,16 +655,15 @@ def _quant_dot_kernel_streamed_abft(x_ref, mats_ref, wq_hbm, sw_hbm, cw_ref,
 
     @pl.when(j == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[...], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        op = _operand_from_q(q, mode)
-        q_ref[...] = op
-        s_ref[...] = s
-        chk_ref[...] = _abft_check_col(op, cw_ref[...])
+        _rotate_into(x_ref, mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows,
+                     chk=(chk_ref, cw_ref[...]))
         acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
     slot = finish()
-    acc = _operand_dot(q_ref[...], w_ring[slot], mode)
+    acc = _operand_dot(q_ref[...],
+                       _weight_operand(w_ring.at[slot], wb_ref, mode, ks),
+                       mode)
     contrib = acc * s_ref[...] * sw_ring[slot]
     o_ref[...] = contrib.astype(o_ref.dtype)
     acc_ref[...] += jnp.sum(contrib, axis=-1, keepdims=True)
@@ -664,9 +776,10 @@ def _pallas_quant_dot(x, wq, sw, plan, interpret: bool, schedule: str,
     x2, m = _rows(x, n)
     d = wq.shape[-1]
     sw2 = sw.reshape(1, d).astype(jnp.float32)
-    bm, bn = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
-                              block_m=plan.block_m, block_n=block_n,
-                              schedule=schedule)
+    dec = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
+                           block_m=plan.block_m, block_n=block_n,
+                           schedule=schedule)
+    bm, bn = dec
     x2, _ = _pad_rows(x2, bm)
     pad_d = (-d) % bn
     if pad_d:
@@ -675,25 +788,28 @@ def _pallas_quant_dot(x, wq, sw, plan, interpret: bool, schedule: str,
     else:
         wq2 = wq
     mp, dp = x2.shape[0], d + pad_d
+    _count_row_blocks(dec, mp // bm, m, n, d, mode)
     common = dict(n=n, mode=mode, compute_dtype=cd)
+    chunks = dict(rows=dec.rotate_rows, ks=dec.convert_rows)
     # rotate_once/revisit let the BlockSpec pipeline slice the weight;
     # streamed takes the weight movement over (ANY-memory-space refs, the
     # kernel DMAs each tile into its two-slot VMEM ring)
     wq_spec = pl.BlockSpec((n, bn), lambda i, j: (0, j))
     sw_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
     if schedule == "rotate_once":
-        kernel = functools.partial(_quant_dot_kernel_rotate_once, **common)
+        kernel = functools.partial(_quant_dot_kernel_rotate_once, **common,
+                                   **chunks)
         scratch = [pltpu.VMEM((bm, n), _scratch_dtype(mode)),
-                   pltpu.VMEM((bm, 1), jnp.float32)]
+                   pltpu.VMEM((bm, 1), jnp.float32)] + _bf16_tile(dec, n)
     elif schedule == "streamed":
         kernel = functools.partial(_quant_dot_kernel_streamed, **common,
-                                   bn=bn, nj=dp // bn)
+                                   **chunks, bn=bn, nj=dp // bn)
         scratch = [pltpu.VMEM((bm, n), _scratch_dtype(mode)),
                    pltpu.VMEM((bm, 1), jnp.float32),
                    pltpu.VMEM((2, n, bn), wq2.dtype),      # weight ring
                    pltpu.VMEM((2, 1, bn), jnp.float32),    # scale ring
                    pltpu.SemaphoreType.DMA((2,)),          # weight sems
-                   pltpu.SemaphoreType.DMA((2,))]          # scale sems
+                   pltpu.SemaphoreType.DMA((2,))] + _bf16_tile(dec, n)
         wq_spec = pl.BlockSpec(memory_space=ANY)
         sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
@@ -722,6 +838,24 @@ def _scratch_dtype(mode: str):
     return jnp.int8 if QSPECS[mode][2] else jnp.bfloat16
 
 
+def _bf16_tile(dec: BlockDecision, n: int) -> list:
+    """The fp8 weight tile's bf16 operand scratch (``_weight_operand``),
+    last in the scratch list; none for int8."""
+    if not dec.convert_rows:
+        return []
+    return [pltpu.VMEM((n, dec.block_n), jnp.bfloat16)]
+
+
+def _count_row_blocks(dec: BlockDecision, row_blocks: int, m: int, n: int,
+                      d: int, mode: str) -> None:
+    """Trace time: add the call's row blocks to
+    ``TRACE_COUNTS[("quant_dot", "row_blocks")]`` -- each fetches, and
+    for fp8 converts, its whole weight once -- and log the decision."""
+    TRACE_COUNTS[("quant_dot", "row_blocks")] += row_blocks
+    _LOG.debug("quant_dot m=%d n=%d d=%d %s: %d row blocks, %r", m, n, d,
+               mode, row_blocks, dec)
+
+
 @functools.partial(jax.jit, static_argnames=("plan", "interpret", "schedule",
                                              "block_n"))
 def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
@@ -745,9 +879,10 @@ def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
     d = wq.shape[-1]
     sw2 = sw.reshape(1, d).astype(jnp.float32)
     cw2 = cw.reshape(1, n).astype(jnp.float32)
-    bm, bn = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
-                              block_m=plan.block_m, block_n=block_n,
-                              schedule=schedule, abft=True)
+    dec = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
+                           block_m=plan.block_m, block_n=block_n,
+                           schedule=schedule, abft=True)
+    bm, bn = dec
     x2, _ = _pad_rows(x2, bm)
     pad_d = (-d) % bn
     if pad_d:
@@ -756,7 +891,9 @@ def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
     else:
         wq2 = wq
     mp, dp = x2.shape[0], d + pad_d
+    _count_row_blocks(dec, mp // bm, m, n, d, mode)
     common = dict(n=n, mode=mode, compute_dtype=cd)
+    chunks = dict(rows=dec.rotate_rows, ks=dec.convert_rows)
     wq_spec = pl.BlockSpec((n, bn), lambda i, j: (0, j))
     sw_spec = pl.BlockSpec((1, bn), lambda i, j: (0, j))
     # chk + acc f32 columns live across the j loop beside q/s
@@ -766,16 +903,16 @@ def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
                       pltpu.VMEM((bm, 1), jnp.float32)]    # acc
     if schedule == "rotate_once":
         kernel = functools.partial(_quant_dot_kernel_rotate_once_abft,
-                                   **common)
-        scratch = verify_scratch
+                                   **common, **chunks)
+        scratch = verify_scratch + _bf16_tile(dec, n)
     elif schedule == "streamed":
         kernel = functools.partial(_quant_dot_kernel_streamed_abft, **common,
-                                   bn=bn, nj=dp // bn)
+                                   **chunks, bn=bn, nj=dp // bn)
         scratch = verify_scratch + [
             pltpu.VMEM((2, n, bn), wq2.dtype),      # weight ring
             pltpu.VMEM((2, 1, bn), jnp.float32),    # scale ring
             pltpu.SemaphoreType.DMA((2,)),          # weight sems
-            pltpu.SemaphoreType.DMA((2,))]          # scale sems
+            pltpu.SemaphoreType.DMA((2,))] + _bf16_tile(dec, n)
         wq_spec = pl.BlockSpec(memory_space=ANY)
         sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
@@ -805,8 +942,8 @@ def _pallas_quant_dot_abft(x, wq, sw, cw, plan, interpret: bool,
 
 
 def _quant_dot_experts_kernel(x_ref, mats_ref, wq_ref, sw_ref, o_ref,
-                              q_ref, s_ref, *, n: int, mode: str,
-                              compute_dtype):
+                              q_ref, s_ref, wb_ref=None, *, n: int,
+                              mode: str, compute_dtype, rows: int, ks: int):
     """Rotate-once grid step on the 3-D (expert, row blocks, out-channel
     blocks) grid: identical to the dense kernel except every ref carries
     a leading per-expert axis of 1. j (innermost) is sequential, so the
@@ -815,19 +952,19 @@ def _quant_dot_experts_kernel(x_ref, mats_ref, wq_ref, sw_ref, o_ref,
 
     @pl.when(pl.program_id(2) == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[0], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        q_ref[...] = _operand_from_q(q, mode)
-        s_ref[...] = s
+        _rotate_into(x_ref.at[0], mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows)
 
-    acc = _operand_dot(q_ref[...], wq_ref[0], mode)
+    acc = _operand_dot(q_ref[...],
+                       _weight_operand(wq_ref.at[0], wb_ref, mode, ks), mode)
     o_ref[0] = (acc * s_ref[...] * sw_ref[0]).astype(o_ref.dtype)
 
 
 def _quant_dot_experts_kernel_streamed(x_ref, mats_ref, wq_hbm, sw_hbm,
                                        o_ref, q_ref, s_ref, w_ring, sw_ring,
-                                       w_sem, s_sem, *, n: int, mode: str,
-                                       compute_dtype, bn: int, nj: int):
+                                       w_sem, s_sem, wb_ref=None, *, n: int,
+                                       mode: str, compute_dtype, rows: int,
+                                       ks: int, bn: int, nj: int):
     """Streamed grid step on the 3-D (expert, row blocks, out-channel
     blocks) grid: the dense streamed kernel with the DMA sources indexed
     by the CURRENT expert (``wq_hbm``/``sw_hbm`` stay whole (E, n, d) /
@@ -852,20 +989,21 @@ def _quant_dot_experts_kernel_streamed(x_ref, mats_ref, wq_hbm, sw_hbm,
 
     @pl.when(j == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[0], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        q_ref[...] = _operand_from_q(q, mode)
-        s_ref[...] = s
+        _rotate_into(x_ref.at[0], mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows)
 
     slot = finish()
-    acc = _operand_dot(q_ref[...], w_ring[slot], mode)
+    acc = _operand_dot(q_ref[...],
+                       _weight_operand(w_ring.at[slot], wb_ref, mode, ks),
+                       mode)
     o_ref[0] = (acc * s_ref[...] * sw_ring[slot]).astype(o_ref.dtype)
 
 
 def _quant_dot_experts_kernel_abft(x_ref, mats_ref, wq_ref, sw_ref, cw_ref,
                                    o_ref, r_ref, q_ref, s_ref, chk_ref,
-                                   acc_ref, *, n: int, mode: str,
-                                   compute_dtype):
+                                   acc_ref, wb_ref=None, *, n: int,
+                                   mode: str, compute_dtype, rows: int,
+                                   ks: int):
     """Rotate-once 3-D expert grid step + ABFT: the dense verified
     kernel with every ref carrying a leading per-expert axis of 1 and
     the checksum tile sliced per CURRENT expert. j restarts per
@@ -874,15 +1012,13 @@ def _quant_dot_experts_kernel_abft(x_ref, mats_ref, wq_ref, sw_ref, cw_ref,
 
     @pl.when(pl.program_id(2) == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[0], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        op = _operand_from_q(q, mode)
-        q_ref[...] = op
-        s_ref[...] = s
-        chk_ref[...] = _abft_check_col(op, cw_ref[0])
+        _rotate_into(x_ref.at[0], mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows,
+                     chk=(chk_ref, cw_ref[0]))
         acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
-    acc = _operand_dot(q_ref[...], wq_ref[0], mode)
+    acc = _operand_dot(q_ref[...],
+                       _weight_operand(wq_ref.at[0], wb_ref, mode, ks), mode)
     contrib = acc * s_ref[...] * sw_ref[0]
     o_ref[0] = contrib.astype(o_ref.dtype)
     acc_ref[...] += jnp.sum(contrib, axis=-1, keepdims=True)
@@ -892,9 +1028,11 @@ def _quant_dot_experts_kernel_abft(x_ref, mats_ref, wq_ref, sw_ref, cw_ref,
 def _quant_dot_experts_kernel_streamed_abft(x_ref, mats_ref, wq_hbm, sw_hbm,
                                             cw_ref, o_ref, r_ref, q_ref,
                                             s_ref, chk_ref, acc_ref, w_ring,
-                                            sw_ring, w_sem, s_sem, *, n: int,
+                                            sw_ring, w_sem, s_sem,
+                                            wb_ref=None, *, n: int,
                                             mode: str, compute_dtype,
-                                            bn: int, nj: int):
+                                            rows: int, ks: int, bn: int,
+                                            nj: int):
     """Streamed 3-D expert grid step + ABFT: DMA ring per (expert, row
     block) exactly as the unverified streamed kernel; the per-expert
     checksum tile arrives through the plain BlockSpec pipeline (outside
@@ -915,16 +1053,15 @@ def _quant_dot_experts_kernel_streamed_abft(x_ref, mats_ref, wq_hbm, sw_hbm,
 
     @pl.when(j == 0)
     def _rotate():
-        q, s = _rotate_quantize_block(x_ref[0], mats_ref, n=n, mode=mode,
-                                      compute_dtype=compute_dtype)
-        op = _operand_from_q(q, mode)
-        q_ref[...] = op
-        s_ref[...] = s
-        chk_ref[...] = _abft_check_col(op, cw_ref[0])
+        _rotate_into(x_ref.at[0], mats_ref, q_ref, s_ref, n=n, mode=mode,
+                     compute_dtype=compute_dtype, rows=rows,
+                     chk=(chk_ref, cw_ref[0]))
         acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
     slot = finish()
-    acc = _operand_dot(q_ref[...], w_ring[slot], mode)
+    acc = _operand_dot(q_ref[...],
+                       _weight_operand(w_ring.at[slot], wb_ref, mode, ks),
+                       mode)
     contrib = acc * s_ref[...] * sw_ring[slot]
     o_ref[0] = contrib.astype(o_ref.dtype)
     acc_ref[...] += jnp.sum(contrib, axis=-1, keepdims=True)
@@ -972,9 +1109,14 @@ def _pallas_quant_dot_experts(x, wq, sw, plan, interpret: bool,
     x3 = jnp.moveaxis(x.reshape(-1, E, cap, n), 1, 0).reshape(E, -1, n)
     m = x3.shape[1]
     sw3 = sw.reshape(E, 1, d).astype(jnp.float32)
-    bm, bn = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
-                              block_m=plan.block_m, block_n=block_n,
-                              schedule=schedule)
+    if schedule == "revisit":
+        # revisit never grew a 3-D body (the A/B baseline is 2-D only):
+        # it runs the rotate-once step, sized as such
+        schedule = "rotate_once"
+    dec = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
+                           block_m=plan.block_m, block_n=block_n,
+                           schedule=schedule)
+    bm, bn = dec
     pad_m, pad_d = (-m) % bm, (-d) % bn
     if pad_m:
         x3 = jnp.pad(x3, ((0, 0), (0, pad_m), (0, 0)))
@@ -983,14 +1125,16 @@ def _pallas_quant_dot_experts(x, wq, sw, plan, interpret: bool,
         wq3 = jnp.pad(wq, ((0, 0), (0, 0), (0, pad_d)))
         sw3 = jnp.pad(sw3, ((0, 0), (0, 0), (0, pad_d)))
     mp, dp = m + pad_m, d + pad_d
+    _count_row_blocks(dec, E * (mp // bm), m, n, d, mode)
+    common = dict(n=n, mode=mode, compute_dtype=cd, rows=dec.rotate_rows,
+                  ks=dec.convert_rows)
     scratch = [pltpu.VMEM((bm, n), _scratch_dtype(mode)),
                pltpu.VMEM((bm, 1), jnp.float32)]
     wq_spec = pl.BlockSpec((1, n, bn), lambda e, i, j: (e, 0, j))
     sw_spec = pl.BlockSpec((1, 1, bn), lambda e, i, j: (e, 0, j))
     if schedule == "streamed":
         kernel = functools.partial(_quant_dot_experts_kernel_streamed,
-                                   n=n, mode=mode, compute_dtype=cd,
-                                   bn=bn, nj=dp // bn)
+                                   **common, bn=bn, nj=dp // bn)
         scratch += [pltpu.VMEM((2, n, bn), wq3.dtype),     # weight ring
                     pltpu.VMEM((2, 1, bn), jnp.float32),   # scale ring
                     pltpu.SemaphoreType.DMA((2,)),         # weight sems
@@ -998,10 +1142,8 @@ def _pallas_quant_dot_experts(x, wq, sw, plan, interpret: bool,
         wq_spec = pl.BlockSpec(memory_space=ANY)
         sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
-        # revisit never grew a 3-D body (the A/B baseline is 2-D only):
-        # anything else runs the rotate-once step
-        kernel = functools.partial(_quant_dot_experts_kernel, n=n,
-                                   mode=mode, compute_dtype=cd)
+        kernel = functools.partial(_quant_dot_experts_kernel, **common)
+    scratch += _bf16_tile(dec, n)
     out = pl.pallas_call(
         kernel,
         grid=(E, mp // bm, dp // bn),
@@ -1043,9 +1185,12 @@ def _pallas_quant_dot_experts_abft(x, wq, sw, cw, plan, interpret: bool,
     m = x3.shape[1]
     sw3 = sw.reshape(E, 1, d).astype(jnp.float32)
     cw3 = cw.reshape(E, 1, n).astype(jnp.float32)
-    bm, bn = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
-                              block_m=plan.block_m, block_n=block_n,
-                              schedule=schedule, abft=True)
+    if schedule == "revisit":
+        schedule = "rotate_once"     # no 3-D revisit body (see above)
+    dec = quant_dot_blocks(n, d, m, x.dtype, cd, mode,
+                           block_m=plan.block_m, block_n=block_n,
+                           schedule=schedule, abft=True)
+    bm, bn = dec
     pad_m, pad_d = (-m) % bm, (-d) % bn
     if pad_m:
         x3 = jnp.pad(x3, ((0, 0), (0, pad_m), (0, 0)))
@@ -1054,6 +1199,9 @@ def _pallas_quant_dot_experts_abft(x, wq, sw, cw, plan, interpret: bool,
         wq3 = jnp.pad(wq, ((0, 0), (0, 0), (0, pad_d)))
         sw3 = jnp.pad(sw3, ((0, 0), (0, 0), (0, pad_d)))
     mp, dp = m + pad_m, d + pad_d
+    _count_row_blocks(dec, E * (mp // bm), m, n, d, mode)
+    common = dict(n=n, mode=mode, compute_dtype=cd, rows=dec.rotate_rows,
+                  ks=dec.convert_rows)
     scratch = [pltpu.VMEM((bm, n), _scratch_dtype(mode)),
                pltpu.VMEM((bm, 1), jnp.float32),
                pltpu.VMEM((bm, 1), jnp.float32),     # chk
@@ -1062,8 +1210,7 @@ def _pallas_quant_dot_experts_abft(x, wq, sw, cw, plan, interpret: bool,
     sw_spec = pl.BlockSpec((1, 1, bn), lambda e, i, j: (e, 0, j))
     if schedule == "streamed":
         kernel = functools.partial(_quant_dot_experts_kernel_streamed_abft,
-                                   n=n, mode=mode, compute_dtype=cd,
-                                   bn=bn, nj=dp // bn)
+                                   **common, bn=bn, nj=dp // bn)
         scratch += [pltpu.VMEM((2, n, bn), wq3.dtype),     # weight ring
                     pltpu.VMEM((2, 1, bn), jnp.float32),   # scale ring
                     pltpu.SemaphoreType.DMA((2,)),         # weight sems
@@ -1071,8 +1218,8 @@ def _pallas_quant_dot_experts_abft(x, wq, sw, cw, plan, interpret: bool,
         wq_spec = pl.BlockSpec(memory_space=ANY)
         sw_spec = pl.BlockSpec(memory_space=ANY)
     else:
-        kernel = functools.partial(_quant_dot_experts_kernel_abft, n=n,
-                                   mode=mode, compute_dtype=cd)
+        kernel = functools.partial(_quant_dot_experts_kernel_abft, **common)
+    scratch += _bf16_tile(dec, n)
     out, resid = pl.pallas_call(
         kernel,
         grid=(E, mp // bm, dp // bn),

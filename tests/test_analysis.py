@@ -92,6 +92,22 @@ def test_kernel_sites_lint_clean(schedule):
         assert stream_events(kj).count("dot") == 1
 
 
+@pytest.mark.parametrize("schedule", ["rotate_once", "streamed"])
+def test_w_down_sites_lint_clean(schedule):
+    """Phi-4-mini's fp8 w_down (n 8192, d 3072) at a decode batch and a
+    prefill bucket: the chunked rotation stays under the j == 0 guard,
+    the kernel is one pallas_call, and its refs fit what the planner
+    charged and the budget."""
+    from repro.analysis.sites import w_down_sites
+
+    sites = w_down_sites(schedule)
+    assert [s.decision.block_m for s in sites] == [32, 64]
+    rep = run_rules(sites, rules=["vmem-budget", "rotate-once-contract",
+                                  "fusion-contract"])
+    assert rep.ok, rep.format_text()
+    assert len(rep.checked) == 3 * len(sites)
+
+
 def test_model_site_lints_clean():
     rep = run_rules(model_sites("llama3_8b"))
     assert rep.ok, rep.format_text()

@@ -132,10 +132,10 @@ def test_quant_dot_plan_validation():
 
 
 def test_qd_fusability_vmem_budget_guard():
-    """fp8 weight tiles cost 3 bytes/element in VMEM (storage + bf16
-    embedding): at the n=2^15 kernel cap even the minimal (n, 128) tile
-    busts the budget, so the plan must take the unfused fallback; int8
-    still fuses."""
+    """fp8 weight tiles cost two storage slots, the bf16 operand and a
+    K-slice of f32 conversion in VMEM: at the n=2^15 kernel cap even the
+    minimal (n, 128) tile busts the budget, so the plan must take the
+    unfused fallback; int8 (two storage slots) still fuses."""
     from repro.core.api import _qd_fusable
 
     assert _qd_fusable(
@@ -631,6 +631,100 @@ def test_quant_dot_blocks_charges_streamed_ring():
     assert (quant_dot_blocks(*args, schedule="streamed",
                              **pinned).vmem_bytes >
             quant_dot_blocks(*args, **pinned).vmem_bytes)
+
+
+# Phi-4-mini's w_down: every row block fetches and converts the whole
+# 25 M-element fp8 weight, so the decision must give the rows the room
+W_DOWN = dict(n=8192, d=3072)
+
+
+@pytest.mark.parametrize("form", ["rotate_once", "streamed", "abft",
+                                  "experts"])
+def test_quant_dot_blocks_w_down_row_blocks(form, monkeypatch):
+    """At Phi-4-mini's w_down (fp8_e4m3, bf16 io) a decode batch of 32
+    rows is one row block and a 1024-row prefill bucket runs in blocks of
+    at least 64 rows, within the VMEM budget; the traced call (the 3-D
+    experts form included) counts exactly those row blocks."""
+    from repro.kernels.quant_dot import (_VMEM_BUDGET_BYTES,
+                                         STREAM_INTERPRET_ENV,
+                                         pallas_quant_dot,
+                                         pallas_quant_dot_experts,
+                                         quant_dot_blocks)
+
+    monkeypatch.setenv(STREAM_INTERPRET_ENV, "1")
+    n, d, bf16, f32, experts = (W_DOWN["n"], W_DOWN["d"], jnp.bfloat16,
+                                jnp.float32, 2)
+    schedule = "streamed" if form == "streamed" else "rotate_once"
+    plan = plan_for(n, dtype=bf16, backend="pallas",
+                    epilogue=QuantEpilogue("fp8_e4m3"))
+    key = ("quant_dot", "row_blocks")
+    for m in (32, 1024):
+        dec = quant_dot_blocks(n, d, m, bf16, bf16, "fp8_e4m3",
+                               schedule=schedule, abft=form == "abft")
+        assert dec.vmem_bytes <= _VMEM_BUDGET_BYTES, dec
+        if m == 32:
+            assert dec.block_m == 32, dec
+        else:
+            assert dec.block_m >= 64 and m % dec.block_m == 0, dec
+        # rows under a leading axis of 2: a shape no other test traces,
+        # so the jitted wrapper traces here and the counter ticks
+        before = registry.TRACE_COUNTS[key]
+        if form == "experts":
+            jax.make_jaxpr(lambda a, w, s: pallas_quant_dot_experts(
+                a, w, s, plan, True, schedule))(
+                jax.ShapeDtypeStruct((2, experts, m // 2, n), bf16),
+                jax.ShapeDtypeStruct((experts, n, d), jnp.float8_e4m3fn),
+                jax.ShapeDtypeStruct((experts, 1, d), f32))
+            want = experts * (m // dec.block_m)
+        else:
+            check = (jax.ShapeDtypeStruct((1, n), f32) if form == "abft"
+                     else None)
+            jax.make_jaxpr(lambda a, w, s: pallas_quant_dot(
+                a, w, s, plan, True, schedule, check=check))(
+                jax.ShapeDtypeStruct((2, m // 2, n), bf16),
+                jax.ShapeDtypeStruct((n, d), jnp.float8_e4m3fn),
+                jax.ShapeDtypeStruct((1, d), f32))
+            want = m // dec.block_m
+        assert registry.TRACE_COUNTS[key] - before == want, (form, m, dec)
+
+
+@pytest.mark.parametrize("schedule", ["rotate_once", "streamed"])
+@pytest.mark.parametrize("mode", ["fp8_e4m3", "int8"])
+def test_quant_dot_chunked_rows_and_k_slices_bitwise(mode, schedule,
+                                                     monkeypatch):
+    """A row block rotated in several chunks, with (fp8) its weight tile
+    converted to bf16 in several K-slices, is bitwise the unfused XLA
+    oracle and the 16-row block of the old tile decision -- and the
+    traced call counts its row blocks, mp // bm."""
+    from repro.kernels.quant_dot import (STREAM_INTERPRET_ENV,
+                                         pallas_quant_dot, quant_dot_blocks,
+                                         xla_quant_dot)
+
+    monkeypatch.setenv(STREAM_INTERPRET_ENV, "1")
+    n, d, m, bf16 = 512, 256, 120, jnp.bfloat16
+    x = _x((m, n), seed=60, dtype=bf16)
+    wq, sw = quantize_weight(_x((n, d), seed=61) * 0.05, mode)
+    plan = plan_for(n, dtype=bf16, backend="pallas",
+                    epilogue=QuantEpilogue(mode))
+    dec = quant_dot_blocks(n, d, m, bf16, bf16, mode, block_n=128,
+                           schedule=schedule)
+    assert dec.block_m >= 2 * dec.rotate_rows, dec
+    if mode == "int8":
+        assert dec.convert_rows == 0
+    else:
+        assert 0 < dec.convert_rows and n >= 2 * dec.convert_rows, dec
+    key = ("quant_dot", "row_blocks")
+    before = registry.TRACE_COUNTS[key]
+    got = pallas_quant_dot(x, wq, sw, plan, True, schedule, 128)
+    mp = -(-m // dec.block_m) * dec.block_m
+    assert registry.TRACE_COUNTS[key] - before == mp // dec.block_m
+    pinned = plan_for(n, dtype=bf16, backend="pallas",
+                      epilogue=QuantEpilogue(mode), block_m=16)
+    old = pallas_quant_dot(x, wq, sw, pinned, True, schedule, 128)
+    want = xla_quant_dot(x, wq, sw, plan, True)
+    got, old, want = (np.asarray(a, np.float32) for a in (got, old, want))
+    assert (got == want).all()
+    assert (got == old).all()
 
 
 def test_quant_dot_schedule_through_public_api(monkeypatch):

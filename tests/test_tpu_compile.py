@@ -127,7 +127,8 @@ def test_quant_dot_over_vmem_budget_warns_once_and_counts():
     key = ("quant_dot", "vmem_unfused")
     registry.WARN_ONCE_SEEN.discard(key)
     before = registry.TRACE_COUNTS[key]
-    # fp8 (3 VMEM bytes/element): a (32768, 128) weight tile is 12 MiB
+    # fp8: a (32768, 128) weight tile charges two 4-MiB storage slots
+    # and an 8-MiB bf16 operand
     big = plan_for(32768, backend="pallas",
                    epilogue=QuantEpilogue("fp8_e4m3"))
     with pytest.warns(RuntimeWarning, match="VMEM budget"):
