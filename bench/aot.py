@@ -1,7 +1,9 @@
 """Compile a cell's programs for a described TPU v5e, without the chip,
-and print each program's ``memory_analysis``: the weight init, prefill,
-insert and decode at the cell's sizes. What the chip's compiler refuses,
-or a program that does not fit, shows here at no chip time.
+and print each program's ``memory_analysis`` (bytes on each chip): the
+weight init, prefill, insert and decode at the cell's sizes, on the
+cell's mesh (``harness.cell_mesh``) over the described chips. What the
+chip's compiler refuses, or a program that does not fit, shows here at
+no chip time.
 
     JAX_PLATFORMS=cpu python3 bench/aot.py --workload <cell>
 """
@@ -23,12 +25,11 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
+    from jax.sharding import NamedSharding, PartitionSpec
 
-    from bench import serve, spec
+    from bench import harness, serve, spec
     from repro.distributed import sharding as shd
     from repro.launch import shapes as shp
-    from repro.launch.mesh import make_mesh
     from repro.launch.steps import jit_serve_step, param_shardings
     from repro.serving import engine as eng
     from repro.serving.cache import make_insert_fn
@@ -39,10 +40,9 @@ def main(argv=None) -> int:
     jax.default_backend = lambda: "tpu"
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
-    dev = topo.devices[0]
-    one = SingleDeviceSharding(dev)
-    mesh = make_mesh((1, 1), ("data", "model"), devices=[dev])
     cell = spec.Cell(spec.load_benchmark(), args.workload)
+    mesh = harness.cell_mesh(cell, topo.devices)
+    whole = NamedSharding(mesh, PartitionSpec())   # replicated
     mix = cell.traffic
     cfg = serve.model_config(cell.config)
 
@@ -64,7 +64,7 @@ def main(argv=None) -> int:
             flush=True)
 
     with mesh:
-        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one)
+        key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole)
         ps = param_shardings(cfg, mesh)
         build = serve.param_builder(cfg, cell.config)
         c = jax.jit(build, out_shardings=ps).lower(key).compile()
@@ -75,7 +75,7 @@ def main(argv=None) -> int:
             per_slot=True)
         caches = sds(shp.cache_specs(cfg, mix["slots"], mix["max_len"]), cs)
         i32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32,
-                                                 sharding=one)
+                                                 sharding=whole)
 
         def rules(fn):
             def wrapped(*a):
@@ -92,7 +92,7 @@ def main(argv=None) -> int:
                                i32(()))
         insert = jax.jit(rules(make_insert_fn(cfg)), donate_argnums=(0,))
         report("insert", insert.lower(
-            caches, sds(kv, jax.tree.map(lambda _: one, kv)),
+            caches, sds(kv, jax.tree.map(lambda _: whole, kv)),
             i32(())).compile())
         report("decode", decode.lower(params, caches, i32((mix["slots"], 1)),
                                       i32((mix["slots"],))).compile())

@@ -122,13 +122,14 @@ def percentile(values, q: float) -> Optional[float]:
 
 class Run:
     """What a metric reader sees: the cell, the open loop's log, the
-    set-up time, the trace's reduction (traced runs), the device's peaks
-    and the configuration's work counts."""
+    set-up time, the trace's reduction (traced runs), one chip's peaks,
+    the number of chips and the configuration's work counts."""
 
     def __init__(self, cell, log, setup_s, trace, peaks, seconds):
         self.cell, self.log, self.setup_s = cell, log, setup_s
         self.trace, self.peaks, self.seconds = trace, peaks, seconds
         self.config, self.mix = cell.config, cell.traffic
+        self.chips = cell.chips
 
     def in_window(self, t: float) -> bool:
         return self.log.open <= t < self.log.close
@@ -167,6 +168,14 @@ class Run:
 
     def matmul_peak(self) -> float:
         return self.peaks["flops"][self.config["program"]["matmul_dtype"]]
+
+
+def cell_mesh(cell, devices):
+    """The cell's mesh over its first ``cell.chips`` devices: (data 1,
+    model chips), the model tensor-parallel over every chip."""
+    from repro.launch.mesh import make_local_mesh
+
+    return make_local_mesh(cell.chips, devices[:cell.chips])
 
 
 def _fallbacks(counts) -> Dict:
@@ -210,11 +219,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     from bench import check, loop, serve, traffic
     from repro.kernels.registry import TRACE_COUNTS
     from repro.launch.env import enable_compile_cache
-    from repro.launch.mesh import make_local_mesh
 
     def say(*a):
         print(*a, file=out, flush=True)
 
+    # the process's counts before this run; the run reads its own ticks
+    counts_at_start = dict(TRACE_COUNTS)
     devices = require_chips(cell.chips)
     peaks = spec.peaks(devices[0].device_kind)
     cache_dir = enable_compile_cache()
@@ -224,7 +234,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
 
     t = time.perf_counter()
     cfg = serve.model_config(cell.config)
-    mesh = make_local_mesh(1, devices[:1])
+    mesh = cell_mesh(cell, devices)
     params = serve.make_params(cfg, cell.config, seed, mesh)
     jax.block_until_ready(params)
     t_params = time.perf_counter() - t
@@ -235,7 +245,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     loop.warm(engine)
     t_warm = time.perf_counter() - t
     planned = traffic.generate(mix, cell.config["vocab_size"], seed, seconds)
-    kernels_before = {k: TRACE_COUNTS[c] for k, c in KERNEL_COUNTERS.items()}
+    kernels_before = {k: TRACE_COUNTS[c] - counts_at_start.get(c, 0)
+                      for k, c in KERNEL_COUNTERS.items()}
 
     trace_dir = os.path.join(spec.ROOT, ".bench_trace", cell.name)
     marks, usage = {}, []
@@ -261,7 +272,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     if trace:
         jax.profiler.stop_trace()
     device = _device_record(devices)
-    counts = dict(TRACE_COUNTS)
+    counts = {k: v - counts_at_start.get(k, 0)
+              for k, v in TRACE_COUNTS.items()}
     setup_s = log.open - t_start
     compiles_in_window = (marks["window_close"][1] - marks["window_open"][1]
                           if "window_close" in marks else None)
@@ -284,7 +296,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
 
     tr = _read_trace(trace_dir) if trace else None
     if tr is not None:
-        device.update(busy_s=tr.busy_s(), window_s=tr.window_s)
+        # window_s is what the trace kept, the window busy_s covers;
+        # window_marked_s the whole window between the harness's marks
+        device.update(busy_s=tr.busy_s(), window_s=tr.window_s,
+                      window_marked_s=tr.marked_s)
     run = Run(cell, log, setup_s, tr, peaks, seconds)
 
     due = run.due_in_window()

@@ -1,6 +1,7 @@
 """Model step, decode: the useful operations of the window's decode
 steps (``work.counts.decode_flops`` of each occupied slot at its depth)
-over their summed host time times the matmul peak (%)."""
+over their summed host time times the matmul peak of every chip the
+cell uses (%)."""
 from bench.work import counts
 
 
@@ -11,4 +12,4 @@ def read(run):
         return None
     ops = sum(counts.decode_flops(run.config, d)
               for _, _, depths in steps for d in depths)
-    return 100.0 * ops / (busy * run.matmul_peak())
+    return 100.0 * ops / (busy * run.matmul_peak() * run.chips)

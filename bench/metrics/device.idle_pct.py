@@ -1,8 +1,9 @@
 """Device: the share of the traced window in which no operation ran on
-the device (%)."""
+the device, averaged over the chips (%). The window is the part the
+trace kept (``Trace.kept_until``)."""
 
 
 def read(run):
-    if run.trace is None or not run.trace.chips:
+    if run.trace is None or not run.trace.chips or not run.trace.window_s:
         return None
     return 100.0 * (1.0 - run.trace.busy_s() / run.trace.window_s)

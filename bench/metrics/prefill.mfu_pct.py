@@ -1,6 +1,7 @@
 """Model step, prefill: the useful operations of the window's admissions
 (``work.counts.prefill_flops`` of each real prompt) over their summed
-host time times the peak the configuration's matmuls run at (%)."""
+host time times the peak the configuration's matmuls run at, on every
+chip the cell uses (%)."""
 from bench.work import counts
 
 
@@ -10,4 +11,4 @@ def read(run):
     if not busy:
         return None
     ops = sum(counts.prefill_flops(run.config, n) for _, _, n in adm)
-    return 100.0 * ops / (busy * run.matmul_peak())
+    return 100.0 * ops / (busy * run.matmul_peak() * run.chips)

@@ -26,19 +26,24 @@ def real_tokens(run, program: str) -> Tuple[List[int], int]:
             int(run.mix["slots"]))
 
 
-def call_work(kernel: str, op_name: str, tokens: int, built_for: int):
+def call_work(kernel: str, op_name: str, tokens: float, built_for: int,
+              shards: int = 1):
     """(ops, bytes) of the useful work of one call, from the shapes in its
-    instruction. The call's rows hold ``built_for`` tokens, each as
-    rows // built_for rows (the grouped transform takes a token's row as
-    g groups; the kernel's own row blocks pad less than a token's worth),
-    of which ``tokens`` are real. Operands and results that have the
-    call's rows count the real rows alone; weights, scales and pass
-    matrices count whole. Every operand is read once and every result
-    written once."""
+    instruction. A step built for ``built_for`` tokens, of which ``tokens``
+    are real, hands the kernel g rows a token (the grouped transform takes
+    a token's row as g groups). A mesh of ``shards`` devices splits them
+    s ways (s = ``shards``, or 1 where it does not divide the rows), so a
+    call holds g * built_for / s rows, which the kernel pads to its row
+    blocks by fewer than built_for / shards. The call counts its share of
+    the real rows, g * tokens / s = (rows * shards // built_for) * tokens
+    / shards; on one device, rows // built_for * tokens. Operands and
+    results that have the call's rows count the real rows alone; weights,
+    scales and pass matrices count whole. Every operand is read once and
+    every result written once."""
     results, operands = call_shapes(op_name)
     (_, x), (_, out) = operands[0], results[0]
     rows = x[0]
-    real = rows // built_for * tokens
+    real = rows * shards // built_for * tokens / shards
 
     def nbytes(shapes):
         return sum(shape_bytes([s]) * (real / rows if s[1][:1] == (rows,)
@@ -52,7 +57,8 @@ def call_work(kernel: str, op_name: str, tokens: int, built_for: int):
 
 def kernel_roofline_pct(run, kernel: str, program: str) -> Optional[float]:
     """The least time of the useful work of every call of ``kernel`` in
-    ``program`` over their summed device time (%)."""
+    ``program`` over their summed device time (%). Each call is held to
+    its own chip's peaks, and the calls of every chip are summed."""
     if run.trace is None:
         return None
     events = run.trace.kernel_events(KERNEL_PATTERNS[kernel], program)
@@ -63,7 +69,7 @@ def kernel_roofline_pct(run, kernel: str, program: str) -> Optional[float]:
     least = 0.0
     for e in events:
         ops, byt = call_work(kernel, e[0], tokens[run.trace.step_of(e)],
-                             built_for)
+                             built_for, run.chips)
         least += counts.roofline_time(ops, byt, run.matmul_peak(),
                                       run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / busy

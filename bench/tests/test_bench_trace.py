@@ -122,7 +122,7 @@ def test_recorded_trace_shows_the_transform_and_no_quant_dot():
     def share(prompt_len, kernel="hadamard"):
         log = SimpleNamespace(open=0.0, admits=[(0.0, 0.15, prompt_len)],
                               decodes=[(0.15, 0.17, [9] * 16)] * 2)
-        run = SimpleNamespace(trace=t, peaks=peaks, log=log,
+        run = SimpleNamespace(trace=t, peaks=peaks, log=log, chips=1,
                               mix={"prefill_len": 2048, "slots": 16},
                               matmul_peak=lambda: peaks["flops"]["fp8_e4m3"])
         return kernel_roofline_pct(run, kernel, "prefill")
@@ -147,10 +147,96 @@ def test_each_kernel_call_counts_the_tokens_of_its_own_step():
                           decodes=[(0.5, 0.7, [3] * 32), (1.0, 1.2, [3] * 8)])
     run = SimpleNamespace(trace=t, log=log, mix={"prefill_len": 2048,
                                                  "slots": 32},
-                          peaks={"hbm_bytes_per_s": 1e30},
+                          peaks={"hbm_bytes_per_s": 1e30}, chips=1,
                           matmul_peak=lambda: 1.0)
     # compute-bound at this peak: each call's operations, at the slots
     # its own step had in use, over the two calls' time
     ops = [2 * n * 8192 * 3072 + n * 8192 * 13 for n in (32, 8)]
     assert kernel_roofline_pct(run, "quant_dot", "decode") \
         == pytest.approx(100 * sum(ops) / ((70 + 160) * 1e-9))
+
+
+# two chips; chip 0's events stop after the second decode step, chip 1
+# keeps all four. ns; a window [0, 2000]
+CUT = {
+    "host": [["bench.window_open", 0, 0], ["bench.admit", 100, 300],
+             ["bench.decode", 500, 200], ["bench.decode", 800, 200],
+             ["bench.decode", 1100, 200], ["bench.decode", 1400, 200],
+             ["bench.window_close", 2000, 0]],
+    "device": {
+        "/device:TPU:0": [[TRANSFORM, 150, 100], [QUANT_DOT, 520, 100],
+                          [QUANT_DOT, 820, 100]],
+        "/device:TPU:1": [[TRANSFORM, 150, 100], [QUANT_DOT, 520, 100],
+                          [QUANT_DOT, 820, 100], [QUANT_DOT, 1120, 100],
+                          [QUANT_DOT, 1420, 100]]},
+}
+
+
+def test_window_ends_at_the_first_span_a_chip_kept_nothing_of():
+    t = Trace(CUT)
+    assert t.kept_until("/device:TPU:0") == 1100
+    assert t.kept_until("/device:TPU:1") == 2000
+    # every reading stops at the third decode step's start, on both chips
+    assert t.window_s == pytest.approx(1100e-9)
+    assert t.marked_s == pytest.approx(2000e-9)
+    assert t.busy_s() == pytest.approx(300e-9)
+    assert [e[1] for e in t.kernel_events(r"_pallas_quant_dot\b")] \
+        == [520, 820, 520, 820]
+    gaps = dict(t.idle_gaps())
+    assert sum(gaps.values()) == pytest.approx(t.window_s - t.busy_s())
+    assert dict(t.device_ops())["decode:_pallas_quant_dot.5"] \
+        == pytest.approx(200e-9)
+
+
+def test_idle_tail_waiting_for_arrivals_keeps_the_window():
+    # the device idles after the last step while the host waits: no
+    # program span goes without its operations
+    tail = {"host": CUT["host"][:4] + [["bench.wait", 1000, 1000],
+                                       ["bench.window_close", 2000, 0]],
+            "device": {"/device:TPU:0": CUT["device"]["/device:TPU:0"]}}
+    t = Trace(tail)
+    assert t.window_s == t.marked_s == pytest.approx(2000e-9)
+    # the last operation ends at 920
+    assert dict(t.idle_gaps())["waiting for arrivals"] \
+        == pytest.approx(1080e-9)
+    assert Trace(SMALL).window_s == Trace(SMALL).marked_s
+
+
+def test_a_plane_with_no_events_is_cut_at_the_opening():
+    from types import SimpleNamespace
+
+    from bench import spec
+
+    empty = dict(CUT, device=dict(CUT["device"], **{"/device:TPU:2": []}))
+    t = Trace(empty)
+    assert t.kept_until("/device:TPU:2") == t.t_close == t.t_open
+    assert t.window_s == 0 and t.busy_s() == 0
+    assert t.idle_gaps() == [] and t.device_ops() == []
+    assert t.kernel_events(r"_pallas_quant_dot\b") == []
+    idle = spec.load_reader("device.idle_pct")
+    assert idle(SimpleNamespace(trace=t)) is None
+
+
+def test_recorded_trace_reads_as_before_the_cut():
+    # the sc2_code slice lost no event: the numbers of the reduction
+    # before the window could end early, to the last digit
+    from types import SimpleNamespace
+
+    from bench import spec
+    from bench.metrics_util import kernel_roofline_pct
+
+    t = Trace(_recorded())
+    assert t.t_close == t.t_marked
+    assert t.busy_s() == 0.181942397
+    assert t.window_s == 0.19063380000000002
+    assert t.idle_gaps() == [
+        ["admission (prefill + insert)", 0.004718798999999974],
+        ["decode step (dispatch + host sync)", 0.003972603999999775]]
+    peaks = spec.peaks("TPU v5 lite")
+    log = SimpleNamespace(open=0.0, admits=[(0.0, 0.15, 2048)],
+                          decodes=[(0.15, 0.17, [9] * 16)] * 2)
+    run = SimpleNamespace(trace=t, peaks=peaks, log=log, chips=1,
+                          mix={"prefill_len": 2048, "slots": 16},
+                          matmul_peak=lambda: peaks["flops"]["fp8_e4m3"])
+    assert kernel_roofline_pct(run, "hadamard", "prefill") \
+        == 67.31941048792855

@@ -4,6 +4,7 @@ import math
 import pytest
 
 from bench.metrics_util import call_work
+from bench.trace import call_shapes, shape_bytes
 from bench.work import counts
 
 TINY = {"hidden_size": 8, "intermediate_size": 16, "num_attention_heads": 2,
@@ -79,3 +80,59 @@ def test_prefill_counts_the_causal_triangle():
 def test_roofline_time_is_the_larger_bound(ops, byt, bound):
     assert math.isclose(counts.roofline_time(ops, byt, 197e12, 819e9),
                         bound)
+
+
+def _rows(instruction, whole, part):
+    return instruction.replace(f"[{whole},", f"[{part},")
+
+
+# (kernel, the whole call, its rows, a quarter's rows as the kernel pads
+# them, the step's tokens); the transform pads 1536 rows to its row block
+SPLITS = [("quant_dot", QUANT_DOT, 32, 8, 32),
+          ("hadamard", TRANSFORM, 6144, 1536, 2048),
+          ("hadamard", TRANSFORM, 6144, 1600, 2048)]
+
+
+@pytest.mark.parametrize("kernel,whole,rows,quarter,built_for", SPLITS)
+@pytest.mark.parametrize("share", [1.0, 0.5, 1 / 32])
+def test_quarter_row_calls_add_up_to_the_whole_call(kernel, whole, rows,
+                                                     quarter, built_for,
+                                                     share):
+    # a mesh of four splits the call's rows four ways: the quarters'
+    # operations and row bytes are the whole call's; each quarter reads
+    # the weight, scales and pass matrices whole on its own chip
+    tokens = built_for * share
+    ops, byt = call_work(kernel, whole, tokens, built_for)
+    part = _rows(whole, rows, quarter)
+    q_ops, q_byt = call_work(kernel, part, tokens, built_for, 4)
+    assert 4 * q_ops == pytest.approx(ops, rel=1e-12)
+    _, operands = call_shapes(whole)
+    shared = shape_bytes(operands[1:])
+    assert 4 * (q_byt - shared) == pytest.approx(byt - shared, rel=1e-12)
+
+
+def test_fewer_tokens_than_shards_count_a_fraction():
+    part = _rows(QUANT_DOT, 32, 8)
+    ops, byt = call_work("quant_dot", part, 1, 32, 4)
+    assert ops == pytest.approx(2 * 0.25 * 8192 * 3072 + 0.25 * 8192 * 13)
+    assert byt > 8192 * 3072
+
+
+@pytest.mark.parametrize("metric", ["decode.mfu_pct", "prefill.mfu_pct"])
+def test_mfu_counts_every_chip(metric):
+    from types import SimpleNamespace
+
+    from bench import harness, spec
+
+    log = SimpleNamespace(open=0.0, close=10.0, admits=[(1.0, 1.5, 7)],
+                          decodes=[(2.0, 2.1, [5, 9]), (3.0, 3.2, [6])])
+    config = dict(TINY, program={"matmul_dtype": "bf16"})
+    peaks = {"flops": {"bf16": 1e6}}
+
+    def read(chips):
+        cell = SimpleNamespace(config=config, traffic={}, chips=chips)
+        run = harness.Run(cell, log, 1.0, None, peaks, 10.0)
+        return spec.load_reader(metric)(run)
+
+    assert read(4) == pytest.approx(read(1) / 4, rel=1e-12)
+    assert read(1) > 0

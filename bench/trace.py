@@ -15,7 +15,8 @@ own event: busy time is the union of the innermost operations, and an
 operation's self time is its duration less its children's. Host and
 device events share the profiler's clock. The window is the span
 between the harness's ``bench.window_open`` and ``bench.window_close``
-marks. A device operation belongs to the program whose harness span
+marks, ended early where the profiler stopped keeping a chip's events
+(``Trace.kept_until``). A device operation belongs to the program whose harness span
 (``bench.admit``: prefill and insert; ``bench.decode``: the decode
 step) holds its start: the harness blocks on each program's result
 inside its span, so the device runs that program's work there. The
@@ -132,8 +133,8 @@ class Trace:
         if "bench.window_open" not in marks:
             raise ValueError("the trace holds no bench.window_open mark")
         self.t_open = marks["bench.window_open"]
-        self.t_close = marks.get("bench.window_close",
-                                 max(e[1] + e[2] for e in self.host))
+        self.t_marked = marks.get("bench.window_close",
+                                  max(e[1] + e[2] for e in self.host))
         self.chips = sorted(extract["device"])
         self.ops = {c: _nest(extract["device"][c]) for c in self.chips}
         spans = [e for e in self.host if e[0] in HOST_DOING]
@@ -147,10 +148,36 @@ class Trace:
                 continue
             self._ordinal.append(seen.get(name, 0))
             seen[name] = seen.get(name, 0) + 1
+        # the reduction's window: every reading below stops here
+        self.t_close = min([self.t_marked]
+                           + [self.kept_until(c) for c in self.chips])
+
+    def kept_until(self, chip: str) -> float:
+        """Where the profiler stopped keeping ``chip``'s operations. Each
+        admission and decode step runs its program on every chip inside
+        its span, so where the window's program spans from one on hold
+        no operation of the chip, its events ended before that span's
+        start; a plane with no operation in the window ended at the
+        opening. The marked close where nothing was dropped. A device
+        idle at the window's end, waiting for arrivals, holds no program
+        span there and keeps its whole window."""
+        last = max((e[1] for e in self.ops[chip]), default=None)
+        if last is None or last < self.t_open:
+            return self.t_open
+        for name, start, _ in self._spans:
+            if name in PROGRAM_OF_SPAN and last < start < self.t_marked:
+                return start
+        return self.t_marked
 
     @property
     def window_s(self) -> float:
+        """The seconds of the window that the trace kept."""
         return (self.t_close - self.t_open) * 1e-9
+
+    @property
+    def marked_s(self) -> float:
+        """The seconds between the harness's window marks."""
+        return (self.t_marked - self.t_open) * 1e-9
 
     def _in_window(self, ops):
         return [e for e in ops if self.t_open <= e[1] < self.t_close]
