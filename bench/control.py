@@ -37,12 +37,11 @@ def control_gaps(config: dict, seed: int, picked, blk):
 
 
 def read_seed(cell, seed: int, seconds: float, devices) -> dict:
-    from bench import check, loop, serve, traffic
-    from repro.launch.mesh import make_local_mesh
+    from bench import check, harness, loop, serve, traffic
 
     mix = cell.traffic
     cfg = serve.model_config(cell.config)
-    mesh = make_local_mesh(1, devices[:1])
+    mesh = harness.cell_mesh(cell, devices)
     engine = serve.make_engine(
         cfg, serve.make_params(cfg, cell.config, seed, mesh), mesh, mix)
     engine.warmup()

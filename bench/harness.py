@@ -269,8 +269,10 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     finally:
         gcs.close()
         gc.unfreeze()
+    t = time.perf_counter()
     if trace:
         jax.profiler.stop_trace()
+    t_stop = time.perf_counter() - t
     device = _device_record(devices)
     counts = {k: v - counts_at_start.get(k, 0)
               for k, v in TRACE_COUNTS.items()}
@@ -294,8 +296,13 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
     del engine
     gc.collect()
 
+    t = time.perf_counter()
     tr = _read_trace(trace_dir) if trace else None
     if tr is not None:
+        say(f"trace: stopped in {t_stop:.3f} s, read and reduced in "
+            f"{time.perf_counter() - t:.3f} s; "
+            f"{sum(len(v) for v in tr.ops.values())} device events on "
+            f"{len(tr.chips)} chips")
         # window_s is what the trace kept, the window busy_s covers;
         # window_marked_s the whole window between the harness's marks
         device.update(busy_s=tr.busy_s(), window_s=tr.window_s,
@@ -320,10 +327,12 @@ def run_cell(cell, seed: int, seconds: float, trace: bool,
         f"{sum(1 for r in due if r.admit is None)}")
 
     metrics = {}
+    t = time.perf_counter()
     for m in cell.metrics(trace):
         v = spec.load_reader(m["name"])(run)
         if v is not None:
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    say(f"metrics: {len(metrics)} read in {time.perf_counter() - t:.3f} s")
 
     t = time.perf_counter()
     cmp = check.compare(cell.config, seed, log.records, mix)

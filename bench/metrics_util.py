@@ -1,11 +1,12 @@
 """What several metric readers share: a kernel's share of its roofline
 in one program, from the trace's events, the harness's log and the work
-counts."""
+counts; and the share of a program's time in which a collective holds
+the device alone."""
 from __future__ import annotations
 
 from typing import List, Optional, Tuple
 
-from bench.trace import call_shapes, shape_bytes
+from bench.trace import COLLECTIVE, call_shapes, shape_bytes
 from bench.work import counts
 
 # the instruction names the program's kernels take in a TPU trace
@@ -73,3 +74,16 @@ def kernel_roofline_pct(run, kernel: str, program: str) -> Optional[float]:
         least += counts.roofline_time(ops, byt, run.matmul_peak(),
                                       run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / busy
+
+
+def exposed_collective_pct(run, program: str) -> Optional[float]:
+    """The time of ``program``'s spans in which a collective runs on a
+    chip and nothing else does, over the spans' time (%), averaged over
+    the chips. Nothing to read on one chip, or where no collective
+    starts in the program's spans."""
+    t = run.trace
+    if t is None or len(t.chips) < 2 or not t.program_s(program) \
+            or not t.kernel_events(COLLECTIVE, program):
+        return None
+    exposed = sum(t.exposed_collective_s(c, program) for c in t.chips)
+    return 100.0 * exposed / len(t.chips) / t.program_s(program)

@@ -31,13 +31,12 @@ def main(argv=None) -> int:
 
     from bench import harness, loop, serve, spec, traffic
     from repro.launch.env import enable_compile_cache
-    from repro.launch.mesh import make_local_mesh
 
     cell = spec.Cell(spec.load_benchmark(), args.workload)
     devices = harness.require_chips(cell.chips)
     enable_compile_cache()
     cfg = serve.model_config(cell.config)
-    mesh = make_local_mesh(1, devices[:1])
+    mesh = harness.cell_mesh(cell, devices)
     params = serve.make_params(cfg, cell.config, args.seed, mesh)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = copy.deepcopy(cell.traffic)

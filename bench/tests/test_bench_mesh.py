@@ -1,8 +1,9 @@
-"""The cell's mesh, and what a run counts as its own: a one-chip cell
-gets the (1, 1) mesh on its one device, a four-chip cell (1, 4) over
-four devices (four forced host devices, in a child process, since JAX
-fixes the device count at its start), and a tiny cell runs whole on
-them. Counter ticks from before a run are not the run's."""
+"""The cell's mesh, and what a run counts as its own: every cell gets
+the mesh of its own ``chips``, a one-chip cell (1, 1) on its one device,
+a four-chip cell (1, 4) over four devices (four forced host devices, in
+a child process, since JAX fixes the device count at its start), and a
+tiny cell runs whole on them. Counter ticks from before a run are not
+the run's."""
 import json
 import os
 import subprocess
@@ -31,27 +32,38 @@ def _four_devices(code: str) -> str:
     return r.stdout
 
 
-@pytest.mark.parametrize("name", CELLS)
-def test_one_chip_cell_gets_its_one_device(name):
-    cell = spec.Cell(spec.load_benchmark(), name)
-    assert cell.chips == 1
-    mesh = harness.cell_mesh(cell, jax.devices())
-    assert dict(mesh.shape) == {"data": 1, "model": 1}
-    assert list(mesh.devices.flat) == jax.devices()[:1]
-
-
-def test_four_chip_cell_splits_the_model_four_ways():
-    out = _four_devices("""
+def check_cell_mesh(bench, name, data_dir=spec.BENCH):
+    """The cell's mesh is (data 1, model chips) over its first chips
+    devices; a four-chip cell's is built in a child with four devices."""
+    cell = spec.Cell(bench, name, data_dir=data_dir)
+    if cell.chips == 1:
+        mesh = harness.cell_mesh(cell, jax.devices())
+        assert dict(mesh.shape) == {"data": 1, "model": 1}
+        assert list(mesh.devices.flat) == jax.devices()[:1]
+        return
+    assert cell.chips == 4
+    out = _four_devices(f"""
 import json, jax
+from pathlib import Path
 from bench import harness, spec
-bench = spec.load_benchmark()
-bench["workloads"][0]["chips"] = 4
-mesh = harness.cell_mesh(spec.Cell(bench, bench["workloads"][0]["name"]),
-                         jax.devices())
+cell = spec.Cell(json.loads({json.dumps(json.dumps(bench))}), {name!r},
+                 data_dir=Path({str(data_dir)!r}))
+mesh = harness.cell_mesh(cell, jax.devices())
 print(json.dumps([dict(mesh.shape), [d.id for d in mesh.devices.flat]]))
 """)
     shape, ids = json.loads(out.splitlines()[-1])
     assert shape == {"data": 1, "model": 4} and ids == [0, 1, 2, 3]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_gets_the_mesh_of_its_chips(name):
+    check_cell_mesh(spec.load_benchmark(), name)
+
+
+def test_four_chip_cell_splits_the_model_four_ways():
+    bench = spec.load_benchmark()
+    bench["workloads"][0]["chips"] = 4
+    check_cell_mesh(bench, bench["workloads"][0]["name"])
 
 
 def test_tiny_cell_runs_whole_on_four_devices():

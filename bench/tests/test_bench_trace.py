@@ -3,9 +3,11 @@ share, self time of nested loop events, kernel time by name and by the
 program whose harness span holds it, idle gaps by what the host did, and
 the call shapes read from an instruction's text."""
 
+import re
+
 import pytest
 
-from bench.trace import Trace, call_shapes, shape_bytes
+from bench.trace import COLLECTIVE, Trace, call_shapes, shape_bytes
 
 TRANSFORM = ("%_pallas_transform.1 = bf16[6144,8192]{1,0:T(8,128)(2,1)} "
              "custom-call(bf16[6144,8192]{1,0:T(8,128)(2,1)} %bitcast.7, "
@@ -240,3 +242,84 @@ def test_recorded_trace_reads_as_before_the_cut():
                           matmul_peak=lambda: peaks["flops"]["fp8_e4m3"])
     assert kernel_roofline_pct(run, "hadamard", "prefill") \
         == 67.31941048792855
+
+
+# four chips, ns; a window [0, 2000] with one admission, two decode
+# steps and a wait. One line holds a chip's operations one after another,
+# nested where the profiler shows one inside another. Chip 0: an
+# all-gather with a fusion nested in it, an all-reduce inside the layer
+# loop, a collective-permute past its span's end, an async all-gather
+# with compute between its -start and -done, a synchronous all-gather,
+# an all-reduce in the wait. Chip 1: no collective. Chip 2: an all-reduce
+# that its nested fusion covers, a reduce-scatter alone. Chip 3: a fusion
+# named after an all-gather; copies and a reduction that are no
+# collectives.
+COLL = {
+    "host": [["bench.window_open", 0, 0], ["bench.admit", 100, 400],
+             ["bench.decode", 600, 300], ["bench.decode", 1000, 400],
+             ["bench.wait", 1500, 400], ["bench.window_close", 2000, 0]],
+    "device": {
+        "/device:TPU:0": [
+            ["%fusion.1 = bf16[8]{0} fusion()", 100, 50],
+            ["%all-gather.1 = bf16[32]{0} all-gather(bf16[8]{0} %a)", 150,
+             150],
+            ["%fusion.7", 160, 100], ["%fusion.2", 300, 100],
+            ["%while.16 = (s32[]) while(s32[] %t)", 610, 280],
+            [QUANT_DOT, 620, 80], ["%all-reduce.2", 700, 60],
+            ["%fusion.3", 760, 120], ["%collective-permute.1", 890, 60],
+            ["%all-gather-start.4", 1010, 10], ["%fusion.4", 1020, 80],
+            ["%fusion.5", 1100, 100], ["%all-gather-done.4", 1200, 50],
+            ["%all-gather.5", 1250, 50], ["%fusion.6", 1300, 50],
+            ["%all-reduce.9", 1600, 50]],
+        "/device:TPU:1": [["%fusion.1", 100, 100], ["%fusion.3", 620, 80],
+                          ["%fusion.4", 1010, 90]],
+        "/device:TPU:2": [
+            ["%fusion.1", 100, 100], ["%reduce-scatter.3", 200, 60],
+            ["%all-reduce.4", 620, 80], ["%fusion.8", 620, 80],
+            ["%fusion.4", 1010, 90]],
+        "/device:TPU:3": [
+            ["%copy-start.2", 700, 20], ["%copy-done.2", 730, 10],
+            ["%fusion.4", 1010, 90], ["%fusion.5", 1210, 40],
+            ["%bitcast_all-gather_fusion.3", 1250, 40],
+            ["%convert_reduce_fusion.6", 1300, 30]]},
+}
+
+
+def test_exposed_collective_time_by_chip_and_program():
+    t = Trace(COLL)
+    assert t.window_s == t.marked_s
+    assert t.program_s("prefill") == pytest.approx(400e-9)
+    assert t.program_s("decode") == pytest.approx(700e-9)
+    exposed = {(c[-1], p): t.exposed_collective_s(c, p)
+               for c in t.chips for p in ("prefill", "decode")}
+    # chip 0: the all-gather less its nested fusion, 10 + 40; in decode
+    # the all-reduce 60, the permute to its span's end 10, the -start 10,
+    # the -done 50 and the synchronous all-gather 50; the wait's
+    # all-reduce counts in no program
+    assert exposed == pytest.approx({
+        ("0", "prefill"): 50e-9, ("0", "decode"): 180e-9,
+        ("1", "prefill"): 0.0, ("1", "decode"): 0.0,
+        ("2", "prefill"): 60e-9, ("2", "decode"): 0.0,
+        ("3", "prefill"): 0.0, ("3", "decode"): 40e-9}, abs=1e-15)
+
+
+def test_collective_readers_average_the_exposed_share_over_chips():
+    from types import SimpleNamespace
+
+    from bench import spec
+
+    run = SimpleNamespace(trace=Trace(COLL))
+    decode = spec.load_reader("collective.decode.exposed_pct")
+    prefill = spec.load_reader("collective.prefill.exposed_pct")
+    assert decode(run) == pytest.approx(100 * (180 + 40) / 4 / 700)
+    assert prefill(run) == pytest.approx(100 * (50 + 60) / 4 / 400)
+    # the same collectives on one chip, four chips without one, and the
+    # recorded one-chip sc2_code trace
+    one = dict(COLL, device={"/device:TPU:0": COLL["device"]["/device:TPU:0"]})
+    none = dict(COLL, device={
+        c: [e for e in ops if not re.search(COLLECTIVE, e[0])]
+        for c, ops in COLL["device"].items()})
+    for extract in (one, none, _recorded()):
+        run = SimpleNamespace(trace=Trace(extract))
+        assert decode(run) is None and prefill(run) is None
+    assert decode(SimpleNamespace(trace=None)) is None

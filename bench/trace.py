@@ -22,7 +22,14 @@ step) holds its start: the harness blocks on each program's result
 inside its span, so the device runs that program's work there. The
 profiler starts at the window's opening, so the n-th span of a kind in
 the trace is the n-th admission or decode step that the harness's log
-holds from the opening on (``step_of``)."""
+holds from the opening on (``step_of``).
+
+A collective is an operation whose instruction name holds one of the
+HLO collective opcodes (``COLLECTIVES``): the synchronous form, the
+``-start`` and ``-done`` halves of an asynchronous one, and a fusion
+named after one. Each counts over its own event: an asynchronous
+collective's wait shows as its ``-done`` operation, and the device time
+between its halves belongs to whatever else runs there."""
 from __future__ import annotations
 
 import bisect
@@ -35,6 +42,10 @@ HOST_DOING = {"bench.admit": "admission (prefill + insert)",
               "bench.decode": "decode step (dispatch + host sync)",
               "bench.wait": "waiting for arrivals"}
 BETWEEN = "harness between steps"
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter",
+               "collective-permute", "all-to-all")
+# on an instruction name, as ``Trace.kernel_events`` takes it
+COLLECTIVE = r"(?<![a-z])(?:%s)" % "|".join(COLLECTIVES)
 _SHAPE = re.compile(r"\b([a-z][a-z0-9]*)\[([\d,]*)\]")
 DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
                "bf16": 2, "f16": 2, "s16": 2, "f32": 4, "s32": 4, "u32": 4}
@@ -106,6 +117,20 @@ def _union(intervals: Iterable[Tuple[float, float]]):
         else:
             merged.append([a, b])
     return merged
+
+
+def _overlap(a, b):
+    """The intersection of two sorted lists of disjoint intervals."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if lo < hi:
+            out.append([lo, hi])
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
 
 
 def _nest(ops):
@@ -195,6 +220,38 @@ class Trace:
             return 0.0
         return sum(sum(b - a for a, b in self.busy_intervals(c))
                    for c in self.chips) * 1e-9 / len(self.chips)
+
+    def program_spans(self, program: str):
+        """The harness spans of ``program`` clipped to the kept window,
+        as a sorted list of disjoint intervals."""
+        lo, hi = self.t_open, self.t_close
+        return _union((max(s, lo), min(s + d, hi)) for n, s, d in self._spans
+                      if PROGRAM_OF_SPAN.get(n) == program
+                      and s < hi and s + d > lo)
+
+    def program_s(self, program: str) -> float:
+        """Seconds of the kept window that ``program``'s spans hold."""
+        return sum(b - a for a, b in self.program_spans(program)) * 1e-9
+
+    def exposed_collective_s(self, chip: str, program: str) -> float:
+        """Seconds of ``program``'s spans in the kept window in which a
+        collective ran on ``chip`` and no other operation of that chip
+        did: the union of its collectives' intervals less the union of
+        its other innermost operations' (an operation the profiler nests
+        inside a collective hides it there)."""
+        rx = re.compile(COLLECTIVE)
+        lo, hi = self.t_open, self.t_close
+        coll, other = [], []
+        for e in self.ops[chip]:
+            if e[1] < hi and e[1] + e[2] > lo:
+                if rx.search(e[0].partition(" = ")[0]):
+                    coll.append((e[1], e[1] + e[2]))
+                elif e[4]:
+                    other.append((e[1], e[1] + e[2]))
+        held = _overlap(_union(coll), self.program_spans(program))
+        hidden = _overlap(held, _union(other))
+        return (sum(b - a for a, b in held)
+                - sum(b - a for a, b in hidden)) * 1e-9
 
     def _span_index(self, t: float) -> Optional[int]:
         i = bisect.bisect_right(self._span_starts, t) - 1
