@@ -28,6 +28,7 @@ __all__ = [
     "kernel_jaxpr",
     "kernel_jaxprs",
     "pallas_call_eqns",
+    "scan_xs_ys",
     "stream_events",
 ]
 
@@ -121,6 +122,20 @@ def dots_outside_pallas(jaxpr) -> int:
     kernel (e.g. the einsum fallback ran)."""
     return sum(1 for e in iter_eqns(jaxpr, into_pallas=False)
                if e.primitive.name == "dot_general")
+
+
+def scan_xs_ys(jaxpr) -> List[Tuple[str, object]]:
+    """``("xs", aval)`` for every operand a ``scan`` anywhere in ``jaxpr``
+    slices per iteration and ``("ys", aval)`` for every result it stacks
+    from its iterations; constants and carry are left out."""
+    out = []
+    for e in iter_eqns(jaxpr):
+        if e.primitive.name != "scan":
+            continue
+        nc, nk = e.params["num_consts"], e.params["num_carry"]
+        out += [("xs", v.aval) for v in e.invars[nc + nk:]]
+        out += [("ys", v.aval) for v in e.outvars[nk:]]
+    return out
 
 
 def stream_events(kjaxpr) -> List[str]:

@@ -25,6 +25,8 @@ What each rule proves:
   device limit.
 * ``donation``           -- donated serving executables actually alias
   their cache buffers in the compiled HLO (no defensive copy).
+* ``scan-cache-xs``      -- no layer scan in a serving trace takes a KV
+  cache stack as xs or returns one as ys (a whole-cache copy a step).
 * ``deprecated-shim-in-trace`` -- no site traces through the
   deprecated ``kernels.ops`` / ``kernels.fused_quant`` shims.
 """
@@ -402,6 +404,31 @@ class Donation(Rule):
                         "computation -- the buffer is duplicated "
                         "instead of updated in place"))
         return out
+
+
+# -------------------------------------------------------- scan-cache-xs
+@register_rule
+class ScanCacheXs(Rule):
+    """A serving trace's layer scans carry the stacked KV caches: a stack
+    taken as the scan's xs is sliced into a per-layer copy, and one
+    returned as ys is stacked back from the layers' copies -- each a
+    whole-cache copy every decode step, where the step needs only its
+    new rows written in place."""
+
+    name = "scan-cache-xs"
+
+    def applies(self, site: Site) -> bool:
+        return (site.kind == "serving" and site.jaxpr is not None
+                and bool(site.kv_leaves))
+
+    def check(self, site: Site) -> List[Violation]:
+        kv = {tuple(s) for s in site.kv_leaves}
+        return [self._v(
+            site, f"a scan takes the KV cache stack {tuple(aval.shape)} as "
+            f"{role} -- every layer's cache is copied out and stacked "
+            "back each step instead of carried and written in place")
+            for role, aval in ju.scan_xs_ys(site.jaxpr)
+            if tuple(aval.shape) in kv]
 
 
 # ----------------------------------------------------- deprecated shims

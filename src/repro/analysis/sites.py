@@ -45,6 +45,7 @@ class Site:
     io_dtype: Any = None
     hlo_text: Optional[str] = None          # compiled HLO (serving sites)
     cache_leaves: Tuple[Tuple[Tuple[int, ...], str], ...] = ()
+    kv_leaves: Tuple[Tuple[int, ...], ...] = ()  # attention K/V stack shapes
     donated: bool = False                   # cache donation is contractual
     qw_calls: int = 0                       # quantize_weight delta in-trace
     shim_calls: Dict[str, int] = dataclasses.field(default_factory=dict)
@@ -235,6 +236,16 @@ def _cache_leaves(caches) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
                  for leaf in jax.tree_util.tree_leaves(caches))
 
 
+def kv_leaves(caches) -> Tuple[Tuple[int, ...], ...]:
+    """Shapes of the attention sites' stacked K/V caches (self- and
+    cross-attention) in a cache tree; scan-state leaves are left out."""
+    import jax
+
+    return tuple(tuple(leaf.shape) for path, leaf in
+                 jax.tree_util.tree_flatten_with_path(caches)[0]
+                 if getattr(path[-1], "key", None) in ("k", "v", "xk", "xv"))
+
+
 def serving_sites(config_name: str, *, backend: str = "xla",
                   engine=None) -> List[Site]:
     """The serving executables: the donated per-slot decode step and
@@ -276,8 +287,8 @@ def serving_sites(config_name: str, *, backend: str = "xla",
     decode = Site(
         name=f"serve_decode[{config_name}/rung{engine._rung}]",
         kind="serving", jaxpr=jaxpr, io_dtype=jnp.dtype(engine.cfg.dtype),
-        hlo_text=hlo, cache_leaves=leaves, donated=True,
-        qw_calls=qw, shim_calls=shim)
+        hlo_text=hlo, cache_leaves=leaves, kv_leaves=kv_leaves(engine.caches),
+        donated=True, qw_calls=qw, shim_calls=shim)
 
     batch = {"tokens": jnp.zeros((1, engine.prefill_len), jnp.int32)}
     out = engine._prefill(engine.params, batch, jnp.asarray(1, jnp.int32))

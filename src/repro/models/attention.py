@@ -200,18 +200,22 @@ def decode_attention(
     cfg,
     p,
     x: jnp.ndarray,
-    cache_k: jnp.ndarray,
-    cache_v: jnp.ndarray,
+    stack_k: jnp.ndarray,
+    stack_v: jnp.ndarray,
+    layer: jnp.ndarray,
     cache_pos: jnp.ndarray,
     positions: jnp.ndarray,
 ):
-    """Single-token decode. x: (B,1,d); cache_k/v: (B,T,KH,hd) rotated+
-    quantized at write time (the FP8 KV-cache path); cache_pos: () int32
+    """Single-token decode of layer ``layer`` against the stacked caches.
+    x: (B,1,d); stack_k/v: (layers,B,T,KH,hd) rotated+quantized at write
+    time (the FP8 KV-cache path); layer: () int32; cache_pos: () int32
     shared by the whole batch (one-shot serving) OR (B,) int32 per-slot
     positions (continuous batching: every slot sits at its own depth in
     its own KV rows, so the write and the causal mask are per-row).
 
-    Returns (y, new_cache_k, new_cache_v)."""
+    The step's new K/V rows are written into the stacks in place, then
+    the layer's view of the updated stacks is attended. Returns
+    (y, new_stack_k, new_stack_v)."""
     B, S, _ = x.shape
     assert S == 1
     q, k, v = _project_qkv(cfg, p, x)
@@ -220,28 +224,26 @@ def decode_attention(
     k = apply_rope_angles(k, ang)
     q, k = _rotate_quant_qk(cfg, q, k)
     v = _v_spec(cfg, v.shape[-1])(v)
-    per_slot = cache_pos.ndim == 1
-    if per_slot:
-        # per-row scatter: slot b writes its token at its own position
-        write = jax.vmap(lambda c, u, s: jax.lax.dynamic_update_slice_in_dim(
-            c, u, s, axis=0))
-        cache_k = write(cache_k, k.astype(cache_k.dtype), cache_pos)
-        cache_v = write(cache_v, v.astype(cache_v.dtype), cache_pos)
-    else:
-        cache_k = jax.lax.dynamic_update_slice_in_dim(cache_k, k.astype(cache_k.dtype), cache_pos, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(cache_v, v.astype(cache_v.dtype), cache_pos, axis=1)
-    T = cache_k.shape[1]
-    kpos = jnp.arange(T, dtype=jnp.int32)
-    if per_slot:
-        m = kpos[None] <= cache_pos[:, None]           # (B,T)
-        if cfg.sliding_window:
-            m &= kpos[None] > (cache_pos[:, None] - cfg.sliding_window)
-        mask = m[:, None, None]                        # (B,1,1,T)
-    else:
-        m = kpos <= cache_pos
-        if cfg.sliding_window:
-            m &= kpos > (cache_pos - cfg.sliding_window)
-        mask = m[None, None, None]                     # (1,1,1,T)
+    # one position per slot (a shared position is every slot's): slot b
+    # writes its row cache_pos[b] of the layer, one scatter into the stack.
+    # Compiled for TPU, a vmap of dynamic_update_slice over the stack's
+    # slot axis transposes the whole stack, and one dynamic_update_slice
+    # at a shared position relayouts the loop's stack on entry and exit.
+    pos = jnp.broadcast_to(cache_pos, (B,))
+    rows = (layer, jnp.arange(B), pos)
+
+    def write(stack, u):
+        return stack.at[rows].set(u[:, 0].astype(stack.dtype), mode="clip",
+                                  indices_are_sorted=True, unique_indices=True)
+
+    stack_k, stack_v = write(stack_k, k), write(stack_v, v)
+    cache_k = jax.lax.dynamic_index_in_dim(stack_k, layer, 0, keepdims=False)
+    cache_v = jax.lax.dynamic_index_in_dim(stack_v, layer, 0, keepdims=False)
+    kpos = jnp.arange(cache_k.shape[1], dtype=jnp.int32)
+    m = kpos[None] <= pos[:, None]                     # (B,T)
+    if cfg.sliding_window:
+        m &= kpos[None] > (pos[:, None] - cfg.sliding_window)
+    mask = m[:, None, None]                            # (B,1,1,T)
     ctx = _sdpa(cfg, q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), mask)
     y = constrain(ctx @ p["wo"], "batch", "seq", None)
-    return y, cache_k, cache_v
+    return y, stack_k, stack_v

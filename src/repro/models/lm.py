@@ -15,8 +15,10 @@ Entry points:
 Every compiled instruction carries a ``jax.named_scope`` path in its
 op_name metadata: ``embed``, ``layers`` (each group's scan), within it
 ``attention`` and ``mlp``, then ``logits``. What falls in ``layers`` but
-in neither block is the scan's own work: slicing each layer's weights
-and caches out of the stacks and stacking the new caches back.
+in neither block is the scan's own work: slicing each layer's weights out
+of the stacks (and, in decode, the small scan-state leaves). Decode
+carries the stacked KV caches through the scan and writes each step's
+new rows in place, so no layer cache is sliced out or stacked back.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.core.wquant import dequant_tree
 from repro.distributed.sharding import constrain
+from repro.kernels.registry import TRACE_COUNTS
 from repro.models import attention as A
 from repro.models import mlp as M
 from repro.models import rwkv as R
@@ -143,30 +146,27 @@ def _apply_block_train(cfg, kind, p, x, positions, enc_out, want_cache: bool):
     return x, aux, cache
 
 
-def _apply_block_decode(cfg, kind, p, x, cache, cache_pos, positions, enc_out):
-    """Single-token block step. Returns (x, new_cache)."""
-    if kind in ("attn", "moe"):
+def _apply_block_decode(cfg, kind, p, x, cache, layer, cache_pos, positions):
+    """Single-token block step. An attention kind's ``cache`` is its site's
+    stacked caches, written in place at ``layer``; a state kind's is this
+    layer's slice. Returns (x, new_cache)."""
+    if kind in ("attn", "moe", "xattn"):
         h = apply_norm(cfg, p["norm1"], x)
         y, ck, cv = A.decode_attention(cfg, p["attn"], h, cache["k"], cache["v"],
-                                       cache_pos, positions)
+                                       layer, cache_pos, positions)
         x = x + y
-        new = {"k": ck, "v": cv}
+        new = dict(cache, k=ck, v=cv)
+        if kind == "xattn":
+            h = apply_norm(cfg, p["norm_x"], x)
+            xkv = tuple(jax.lax.dynamic_index_in_dim(cache[n], layer, 0, keepdims=False)
+                        for n in ("xk", "xv"))
+            x = x + A.apply_cross_attention(cfg, p["xattn"], h, xkv)
         h = apply_norm(cfg, p["norm2"], x)
         if kind == "moe":
             y, _ = M.apply_moe(cfg, p["moe"], h)
         else:
             y = M.apply_mlp(cfg, p["mlp"], h)
         x = x + y
-    elif kind == "xattn":
-        h = apply_norm(cfg, p["norm1"], x)
-        y, ck, cv = A.decode_attention(cfg, p["attn"], h, cache["k"], cache["v"],
-                                       cache_pos, positions)
-        x = x + y
-        h = apply_norm(cfg, p["norm_x"], x)
-        x = x + A.apply_cross_attention(cfg, p["xattn"], h, (cache["xk"], cache["xv"]))
-        new = {"k": ck, "v": cv, "xk": cache["xk"], "xv": cache["xv"]}
-        h = apply_norm(cfg, p["norm2"], x)
-        x = x + M.apply_mlp(cfg, p["mlp"], h)
     elif kind == "mamba":
         h = apply_norm(cfg, p["norm1"], x)
         st = S.MambaState(cache["ssm"], cache["conv_x"], cache["conv_bc"])
@@ -424,7 +424,12 @@ def lm_decode_step(cfg: ModelConfig, params, caches, tokens, cache_pos):
     tokens already in the cache, shared by the whole batch) OR (B,) int32
     per-slot positions -- the continuous-batching form, where every batch
     row is an independent request slot at its own depth (serving.engine).
-    Returns (logits, new_caches)."""
+
+    Each group's scan carries its attention sites' stacked caches with the
+    layer index: every layer writes its new K/V rows into the stacks in
+    place and attends its own layer's view, so a donated cache stays one
+    buffer through the step. Scan-state leaves (mamba, rwkv) are small and
+    ride as the scan's xs/ys. Returns (logits, new_caches)."""
     with jax.named_scope("embed"):
         emb = dequant_tree(params["emb"], jnp.dtype(cfg.dtype))
         x = jnp.take(emb, tokens, axis=0)
@@ -444,21 +449,34 @@ def lm_decode_step(cfg: ModelConfig, params, caches, tokens, cache_pos):
     new_caches = []
     for (pattern, repeats), gp, cache_stack in zip(cfg.groups, params["groups"], caches):
         gspecs = _group_specs(cfg, pattern) if cfg.weight_quant == "int8" else None
+        carried = {}
+        scanned = {}
+        for j, kind in enumerate(pattern):
+            site = f"p{j}"
+            if _KIND_HAS_ATTN[kind]:
+                carried[site] = cache_stack[site]
+                TRACE_COUNTS[("decode_cache", "in_place")] += 1
+            else:
+                scanned[site] = cache_stack[site]
+                TRACE_COUNTS[("decode_cache", "scanned")] += len(cache_stack[site])
 
-        def body(x, inp, _pattern=pattern, _gspecs=gspecs):
+        def body(carry, inp, _pattern=pattern, _gspecs=gspecs):
+            x, kv, layer = carry
             lp, lc = inp
             if _gspecs is not None:
                 lp = _dequant_layer(cfg, lp, _gspecs, x.dtype)
             else:
                 lp = dequant_tree(lp, x.dtype)
-            new_c = {}
+            kv, new_c = dict(kv), {}
             for j, kind in enumerate(_pattern):
-                x, nc = _apply_block_decode(cfg, kind, lp[f"p{j}"], x,
-                                            lc[f"p{j}"], cache_pos, positions, None)
-                new_c[f"p{j}"] = nc
-            return x, new_c
+                site = f"p{j}"
+                out, cache = (kv, kv) if site in kv else (new_c, lc)
+                x, out[site] = _apply_block_decode(cfg, kind, lp[site], x, cache[site],
+                                                   layer, cache_pos, positions)
+            return (x, kv, layer + 1), new_c
 
         with jax.named_scope("layers"):
-            x, new_stack = jax.lax.scan(body, x, (gp, cache_stack))
-        new_caches.append(new_stack)
+            (x, carried, _), scanned = jax.lax.scan(
+                body, (x, carried, jnp.zeros((), jnp.int32)), (gp, scanned))
+        new_caches.append({**carried, **scanned})
     return _logits(cfg, params, x), new_caches

@@ -16,7 +16,7 @@ from repro.analysis.mutations import mutant_sites
 from repro.analysis.sites import Site
 
 EXPECTED_RULES = {"fusion-contract", "rotate-once-contract", "dma-safety",
-                  "dtype-flow", "vmem-budget", "donation",
+                  "dtype-flow", "vmem-budget", "donation", "scan-cache-xs",
                   "deprecated-shim-in-trace"}
 
 
@@ -166,6 +166,52 @@ def test_dtype_flow_flags_cache_dequant():
                          cache_leaves=leaves)
     assert not run_rules([mk(bad)], rules=["dtype-flow"]).ok
     assert run_rules([mk(good)], rules=["dtype-flow"]).ok
+
+
+@pytest.mark.parametrize("step,flagged", [("xs_scanned", True),
+                                          ("carried", False)])
+def test_scan_cache_xs_flags_an_xs_scanned_decode(step, flagged):
+    """The decode step that scans its KV stacks as xs/ys is flagged, at
+    every stack it slices out and stacks back; the carried step is not."""
+    import functools
+
+    from repro.analysis.sites import kv_leaves
+    from repro.configs import get_config
+    from repro.launch import shapes as shp
+    from repro.models import init_lm, lm_decode_step
+    from xs_decode import xs_decode_step
+
+    cfg = get_config("llama3_8b").scaled_down()
+    params = jax.eval_shape(lambda: init_lm(jax.random.PRNGKey(0), cfg))
+    caches = shp.cache_specs(cfg, 2, 16)
+    fn = {"xs_scanned": xs_decode_step, "carried": lm_decode_step}[step]
+    jaxpr = jax.make_jaxpr(functools.partial(fn, cfg))(
+        params, caches, jnp.zeros((2, 1), jnp.int32),
+        jnp.zeros((2,), jnp.int32))
+    site = Site(name=step, kind="serving", jaxpr=jaxpr,
+                kv_leaves=kv_leaves(caches))
+    rep = run_rules([site], rules=["scan-cache-xs"])
+    assert rep.checked == [(step, "scan-cache-xs")]
+    if flagged:
+        # K and V, each sliced as xs and stacked back as ys
+        assert len(rep.violations) == 4, rep.format_text()
+        assert all("as xs" in v.message or "as ys" in v.message
+                   for v in rep.violations)
+    else:
+        assert rep.ok, rep.format_text()
+
+
+def test_serving_sites_lint_clean():
+    """The engine's donated decode and insert executables pass every
+    rule: the decode scan carries its KV stacks (scan-cache-xs ran on
+    the decode site) and the donated cache is aliased, never copied."""
+    from repro.analysis import serving_sites
+
+    rep = run_rules(serving_sites("llama3_8b"))
+    assert rep.ok, rep.format_text()
+    ran = {(site.split("[")[0], r) for site, r in rep.checked}
+    assert {("serve_decode", "scan-cache-xs"), ("serve_decode", "donation"),
+            ("serve_insert", "donation")} <= ran
 
 
 def test_deprecated_shim_rule_fires_on_shim_trace():
