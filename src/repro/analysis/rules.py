@@ -409,9 +409,9 @@ class Donation(Rule):
 # -------------------------------------------------------- scan-cache-xs
 @register_rule
 class ScanCacheXs(Rule):
-    """A serving trace's layer scans carry the stacked KV caches: a stack
-    taken as the scan's xs is sliced into a per-layer copy, and one
-    returned as ys is stacked back from the layers' copies -- each a
+    """A serving trace's layer scans take no stacked KV cache as xs or ys:
+    a stack taken as the scan's xs is sliced into a per-layer copy, and
+    one returned as ys is stacked back from the layers' copies -- each a
     whole-cache copy every decode step, where the step needs only its
     new rows written in place."""
 
@@ -426,7 +426,7 @@ class ScanCacheXs(Rule):
         return [self._v(
             site, f"a scan takes the KV cache stack {tuple(aval.shape)} as "
             f"{role} -- every layer's cache is copied out and stacked "
-            "back each step instead of carried and written in place")
+            "back each step instead of read in place and written by row")
             for role, aval in ju.scan_xs_ys(site.jaxpr)
             if tuple(aval.shape) in kv]
 

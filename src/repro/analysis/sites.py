@@ -236,16 +236,6 @@ def _cache_leaves(caches) -> Tuple[Tuple[Tuple[int, ...], str], ...]:
                  for leaf in jax.tree_util.tree_leaves(caches))
 
 
-def kv_leaves(caches) -> Tuple[Tuple[int, ...], ...]:
-    """Shapes of the attention sites' stacked K/V caches (self- and
-    cross-attention) in a cache tree; scan-state leaves are left out."""
-    import jax
-
-    return tuple(tuple(leaf.shape) for path, leaf in
-                 jax.tree_util.tree_flatten_with_path(caches)[0]
-                 if getattr(path[-1], "key", None) in ("k", "v", "xk", "xv"))
-
-
 def serving_sites(config_name: str, *, backend: str = "xla",
                   engine=None) -> List[Site]:
     """The serving executables: the donated per-slot decode step and
@@ -257,6 +247,8 @@ def serving_sites(config_name: str, *, backend: str = "xla",
 
     import jax
     import jax.numpy as jnp
+
+    from repro.models.lm import kv_stacks
 
     if engine is None:
         from repro.configs import get_config
@@ -287,7 +279,8 @@ def serving_sites(config_name: str, *, backend: str = "xla",
     decode = Site(
         name=f"serve_decode[{config_name}/rung{engine._rung}]",
         kind="serving", jaxpr=jaxpr, io_dtype=jnp.dtype(engine.cfg.dtype),
-        hlo_text=hlo, cache_leaves=leaves, kv_leaves=kv_leaves(engine.caches),
+        hlo_text=hlo, cache_leaves=leaves,
+        kv_leaves=tuple(tuple(a.shape) for a in kv_stacks(engine.cfg, engine.caches)),
         donated=True, qw_calls=qw, shim_calls=shim)
 
     batch = {"tokens": jnp.zeros((1, engine.prefill_len), jnp.int32)}

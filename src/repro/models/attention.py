@@ -206,16 +206,19 @@ def decode_attention(
     cache_pos: jnp.ndarray,
     positions: jnp.ndarray,
 ):
-    """Single-token decode of layer ``layer`` against the stacked caches.
-    x: (B,1,d); stack_k/v: (layers,B,T,KH,hd) rotated+quantized at write
-    time (the FP8 KV-cache path); layer: () int32; cache_pos: () int32
-    shared by the whole batch (one-shot serving) OR (B,) int32 per-slot
-    positions (continuous batching: every slot sits at its own depth in
-    its own KV rows, so the write and the causal mask are per-row).
+    """Single-token decode of layer ``layer`` against the stacked caches,
+    which it only reads. x: (B,1,d); stack_k/v: (layers,B,T,KH,hd)
+    rotated+quantized at write time (the FP8 KV-cache path); layer: ()
+    int32; cache_pos: () int32 shared by the whole batch (one-shot
+    serving) OR (B,) int32 per-slot positions (continuous batching: every
+    slot sits at its own depth in its own KV rows, so the new row and the
+    causal mask are per-row).
 
-    The step's new K/V rows are written into the stacks in place, then
-    the layer's view of the updated stacks is attended. Returns
-    (y, new_stack_k, new_stack_v)."""
+    Slot b's new K/V row stands in for row cache_pos[b] of the layer's
+    view, so the layer attends the updated cache without writing it; the
+    caller writes the rows once the layer scan is over
+    (``write_kv_rows``). Returns (y, k_row, v_row), rows (B,KH,hd) in the
+    cache dtype."""
     B, S, _ = x.shape
     assert S == 1
     q, k, v = _project_qkv(cfg, p, x)
@@ -224,26 +227,36 @@ def decode_attention(
     k = apply_rope_angles(k, ang)
     q, k = _rotate_quant_qk(cfg, q, k)
     v = _v_spec(cfg, v.shape[-1])(v)
-    # one position per slot (a shared position is every slot's): slot b
-    # writes its row cache_pos[b] of the layer, one scatter into the stack.
-    # Compiled for TPU, a vmap of dynamic_update_slice over the stack's
-    # slot axis transposes the whole stack, and one dynamic_update_slice
-    # at a shared position relayouts the loop's stack on entry and exit.
-    pos = jnp.broadcast_to(cache_pos, (B,))
-    rows = (layer, jnp.arange(B), pos)
+    k_row, v_row = k[:, 0].astype(stack_k.dtype), v[:, 0].astype(stack_v.dtype)
+    T = stack_k.shape[2]
+    pos = jnp.broadcast_to(cache_pos, (B,))            # a shared position is every slot's
+    kpos = jnp.arange(T, dtype=jnp.int32)
+    # the row write_kv_rows lands on: dynamic_update_slice clamps its start
+    hit = (kpos[None] == jnp.clip(pos, 0, T - 1)[:, None])[:, :, None, None]
 
-    def write(stack, u):
-        return stack.at[rows].set(u[:, 0].astype(stack.dtype), mode="clip",
-                                  indices_are_sorted=True, unique_indices=True)
+    def view(stack, row):
+        c = jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+        return jnp.where(hit, row[:, None], c).astype(q.dtype)
 
-    stack_k, stack_v = write(stack_k, k), write(stack_v, v)
-    cache_k = jax.lax.dynamic_index_in_dim(stack_k, layer, 0, keepdims=False)
-    cache_v = jax.lax.dynamic_index_in_dim(stack_v, layer, 0, keepdims=False)
-    kpos = jnp.arange(cache_k.shape[1], dtype=jnp.int32)
     m = kpos[None] <= pos[:, None]                     # (B,T)
     if cfg.sliding_window:
         m &= kpos[None] > (pos[:, None] - cfg.sliding_window)
     mask = m[:, None, None]                            # (B,1,1,T)
-    ctx = _sdpa(cfg, q, cache_k.astype(q.dtype), cache_v.astype(q.dtype), mask)
+    ctx = _sdpa(cfg, q, view(stack_k, k_row), view(stack_v, v_row), mask)
     y = constrain(ctx @ p["wo"], "batch", "seq", None)
-    return y, stack_k, stack_v
+    return y, k_row, v_row
+
+
+def write_kv_rows(stack: jnp.ndarray, rows: jnp.ndarray, cache_pos: jnp.ndarray):
+    """Write a step's new rows into a stacked cache: rows (layers,B,KH,hd)
+    land in stack (layers,B,T,KH,hd) at row cache_pos[b] of slot b (a ()
+    cache_pos is every slot's), every layer at once. One
+    dynamic_update_slice a slot keeps the donated stack in place at any
+    head count; compiled for TPU, a scatter makes the compiler relayout
+    the whole stack when a chip holds few KV heads."""
+    B = rows.shape[1]
+    pos = jnp.broadcast_to(cache_pos, (B,))
+    for b in range(B):
+        stack = jax.lax.dynamic_update_slice(
+            stack, rows[:, b, None, None].astype(stack.dtype), (0, b, pos[b], 0, 0))
+    return stack

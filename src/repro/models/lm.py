@@ -17,13 +17,14 @@ op_name metadata: ``embed``, ``layers`` (each group's scan), within it
 ``attention`` and ``mlp``, then ``logits``. What falls in ``layers`` but
 in neither block is the scan's own work: slicing each layer's weights out
 of the stacks (and, in decode, the small scan-state leaves). Decode
-carries the stacked KV caches through the scan and writes each step's
-new rows in place, so no layer cache is sliced out or stacked back.
+reads the stacked KV caches in the scan without writing them and writes
+each step's new rows in place after it, so no layer cache is sliced out
+or stacked back.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,19 @@ from repro.models.config import ModelConfig
 # --------------------------------------------------------------- per-kind
 _KIND_HAS_ATTN = {"attn": True, "moe": True, "xattn": True, "enc_attn": True,
                   "mamba": False, "rwkv": False}
+
+
+def attention_sites(pattern) -> List[str]:
+    """The sites of a group's pattern whose caches are stacked K/V."""
+    return [f"p{j}" for j, kind in enumerate(pattern) if _KIND_HAS_ATTN[kind]]
+
+
+def kv_stacks(cfg: ModelConfig, caches) -> List[jnp.ndarray]:
+    """Every stacked K/V cache (self- and cross-attention) of a decoder
+    cache tree, in group order; scan-state leaves are left out."""
+    return [leaf for (pattern, _), stack in zip(cfg.groups, caches)
+            for site in attention_sites(pattern)
+            for leaf in jax.tree.leaves(stack[site])]
 
 
 def _init_block(key, cfg: ModelConfig, kind: str):
@@ -148,14 +162,15 @@ def _apply_block_train(cfg, kind, p, x, positions, enc_out, want_cache: bool):
 
 def _apply_block_decode(cfg, kind, p, x, cache, layer, cache_pos, positions):
     """Single-token block step. An attention kind's ``cache`` is its site's
-    stacked caches, written in place at ``layer``; a state kind's is this
-    layer's slice. Returns (x, new_cache)."""
+    stacked caches, which it reads at ``layer``, and it returns the step's
+    new (k, v) rows to write; a state kind's is this layer's slice, which
+    it returns updated. Returns (x, new)."""
     if kind in ("attn", "moe", "xattn"):
         h = apply_norm(cfg, p["norm1"], x)
-        y, ck, cv = A.decode_attention(cfg, p["attn"], h, cache["k"], cache["v"],
-                                       layer, cache_pos, positions)
+        y, k_row, v_row = A.decode_attention(cfg, p["attn"], h, cache["k"], cache["v"],
+                                             layer, cache_pos, positions)
         x = x + y
-        new = dict(cache, k=ck, v=cv)
+        new = (k_row, v_row)
         if kind == "xattn":
             h = apply_norm(cfg, p["norm_x"], x)
             xkv = tuple(jax.lax.dynamic_index_in_dim(cache[n], layer, 0, keepdims=False)
@@ -425,11 +440,14 @@ def lm_decode_step(cfg: ModelConfig, params, caches, tokens, cache_pos):
     per-slot positions -- the continuous-batching form, where every batch
     row is an independent request slot at its own depth (serving.engine).
 
-    Each group's scan carries its attention sites' stacked caches with the
-    layer index: every layer writes its new K/V rows into the stacks in
-    place and attends its own layer's view, so a donated cache stays one
-    buffer through the step. Scan-state leaves (mamba, rwkv) are small and
-    ride as the scan's xs/ys. Returns (logits, new_caches)."""
+    Each group's scan reads its attention sites' stacked caches without
+    writing them: every layer attends its own layer's view with the
+    step's new row standing in at each slot's position, and returns the
+    new K/V rows, which are written into the stacks in place once the
+    scan is over (one update a slot, covering every layer). A donated
+    cache so stays one buffer through the step. Scan-state leaves
+    (mamba, rwkv) are small and ride as the scan's xs/ys. Returns
+    (logits, new_caches)."""
     with jax.named_scope("embed"):
         emb = dequant_tree(params["emb"], jnp.dtype(cfg.dtype))
         x = jnp.take(emb, tokens, axis=0)
@@ -449,34 +467,32 @@ def lm_decode_step(cfg: ModelConfig, params, caches, tokens, cache_pos):
     new_caches = []
     for (pattern, repeats), gp, cache_stack in zip(cfg.groups, params["groups"], caches):
         gspecs = _group_specs(cfg, pattern) if cfg.weight_quant == "int8" else None
-        carried = {}
-        scanned = {}
-        for j, kind in enumerate(pattern):
-            site = f"p{j}"
-            if _KIND_HAS_ATTN[kind]:
-                carried[site] = cache_stack[site]
-                TRACE_COUNTS[("decode_cache", "in_place")] += 1
-            else:
-                scanned[site] = cache_stack[site]
-                TRACE_COUNTS[("decode_cache", "scanned")] += len(cache_stack[site])
+        kv = {site: cache_stack[site] for site in attention_sites(pattern)}
+        scanned = {site: c for site, c in cache_stack.items() if site not in kv}
+        TRACE_COUNTS[("decode_cache", "in_place")] += len(kv)
+        TRACE_COUNTS[("decode_cache", "scanned")] += len(jax.tree.leaves(scanned))
 
-        def body(carry, inp, _pattern=pattern, _gspecs=gspecs):
-            x, kv, layer = carry
+        def body(carry, inp, _pattern=pattern, _gspecs=gspecs, _kv=kv):
+            x, layer = carry
             lp, lc = inp
             if _gspecs is not None:
                 lp = _dequant_layer(cfg, lp, _gspecs, x.dtype)
             else:
                 lp = dequant_tree(lp, x.dtype)
-            kv, new_c = dict(kv), {}
+            rows, new_c = {}, {}
             for j, kind in enumerate(_pattern):
                 site = f"p{j}"
-                out, cache = (kv, kv) if site in kv else (new_c, lc)
-                x, out[site] = _apply_block_decode(cfg, kind, lp[site], x, cache[site],
+                out = rows if site in _kv else new_c
+                x, out[site] = _apply_block_decode(cfg, kind, lp[site], x,
+                                                   _kv.get(site, lc.get(site)),
                                                    layer, cache_pos, positions)
-            return (x, kv, layer + 1), new_c
+            return (x, layer + 1), (rows, new_c)
 
         with jax.named_scope("layers"):
-            (x, carried, _), scanned = jax.lax.scan(
-                body, (x, carried, jnp.zeros((), jnp.int32)), (gp, scanned))
-        new_caches.append({**carried, **scanned})
+            (x, _), (rows, scanned) = jax.lax.scan(
+                body, (x, jnp.zeros((), jnp.int32)), (gp, scanned))
+            kv = {site: dict(c, k=A.write_kv_rows(c["k"], rows[site][0], cache_pos),
+                             v=A.write_kv_rows(c["v"], rows[site][1], cache_pos))
+                  for site, c in kv.items()}
+        new_caches.append({**kv, **scanned})
     return _logits(cfg, params, x), new_caches

@@ -172,13 +172,14 @@ def test_dtype_flow_flags_cache_dequant():
                                           ("carried", False)])
 def test_scan_cache_xs_flags_an_xs_scanned_decode(step, flagged):
     """The decode step that scans its KV stacks as xs/ys is flagged, at
-    every stack it slices out and stacks back; the carried step is not."""
+    every stack it slices out and stacks back; the step that reads the
+    stacks in place and writes only their new rows is not."""
     import functools
 
-    from repro.analysis.sites import kv_leaves
     from repro.configs import get_config
     from repro.launch import shapes as shp
     from repro.models import init_lm, lm_decode_step
+    from repro.models.lm import kv_stacks
     from xs_decode import xs_decode_step
 
     cfg = get_config("llama3_8b").scaled_down()
@@ -189,7 +190,7 @@ def test_scan_cache_xs_flags_an_xs_scanned_decode(step, flagged):
         params, caches, jnp.zeros((2, 1), jnp.int32),
         jnp.zeros((2,), jnp.int32))
     site = Site(name=step, kind="serving", jaxpr=jaxpr,
-                kv_leaves=kv_leaves(caches))
+                kv_leaves=tuple(tuple(a.shape) for a in kv_stacks(cfg, caches)))
     rep = run_rules([site], rules=["scan-cache-xs"])
     assert rep.checked == [(step, "scan-cache-xs")]
     if flagged:
@@ -203,7 +204,7 @@ def test_scan_cache_xs_flags_an_xs_scanned_decode(step, flagged):
 
 def test_serving_sites_lint_clean():
     """The engine's donated decode and insert executables pass every
-    rule: the decode scan carries its KV stacks (scan-cache-xs ran on
+    rule: the decode scan takes no KV stack as xs/ys (scan-cache-xs ran on
     the decode site) and the donated cache is aliased, never copied."""
     from repro.analysis import serving_sites
 

@@ -1,9 +1,9 @@
-"""The decode layer scan carries the stacked KV caches: no scan slices an
-attention cache out as xs or stacks one back as ys, every write to a
-stack lands one row per slot in one layer, and the step is bitwise the
-xs/ys decode it replaced (``tests/xs_decode.py``), across attention,
-expert, mixed scan-state and cross-attention stacks, at per-slot and
-shared positions."""
+"""The decode layer scan reads the stacked KV caches in place: no scan
+slices an attention cache out as xs or stacks one back as ys, the only
+writes to a stack land one row per layer of one slot, and the step is
+bitwise the xs/ys decode it replaced (``tests/xs_decode.py``), across
+attention, expert, mixed scan-state and cross-attention stacks, at
+per-slot and shared positions."""
 import functools
 
 import jax
@@ -12,17 +12,17 @@ import numpy as np
 import pytest
 
 from repro.analysis.jaxpr_utils import iter_eqns, scan_xs_ys
-from repro.analysis.sites import kv_leaves
 from repro.configs import get_config
 from repro.core.quant import QuantConfig
 from repro.kernels.registry import TRACE_COUNTS
 from repro.launch import shapes as shp
 from repro.models import init_lm, lm_decode_step
+from repro.models.lm import kv_stacks
 from xs_decode import xs_decode_step
 
 SLOTS, MAX_LEN = 4, 24
 
-# (attention sites whose stacks ride in the carry, state leaves scanned)
+# (attention sites read in place, state leaves scanned as xs/ys)
 ARCHS = {"llama3_8b": (1, 0), "mixtral_8x7b": (1, 0),
          "zamba2_7b": (1, 18), "whisper_base": (1, 0)}
 
@@ -66,10 +66,7 @@ def _tokens(cfg, step):
 @pytest.mark.parametrize("arch", sorted(ARCHS))
 def test_decode_scan_carries_the_kv_stacks(arch, mode):
     cfg, params, caches = _setup(arch)
-    kv = set(kv_leaves(caches))
-    written = {tuple(s.shape) for path, s in
-               jax.tree_util.tree_flatten_with_path(caches)[0]
-               if path[-1].key in ("k", "v")}
+    kv = {tuple(a.shape) for a in kv_stacks(cfg, caches)}
     before = dict(TRACE_COUNTS)
     jaxpr = jax.make_jaxpr(functools.partial(lm_decode_step, cfg))(
         params, caches, _tokens(cfg, 0), _positions(mode, 0))
@@ -86,17 +83,17 @@ def test_decode_scan_carries_the_kv_stacks(arch, mode):
     writes = 0
     for e in iter_eqns(jaxpr):
         name = e.primitive.name
-        if name not in ("dynamic_update_slice", "scatter"):
+        if name not in ("dynamic_update_slice", "scatter", "scatter-add"):
             continue
-        if tuple(e.invars[0].aval.shape) not in written:
+        stack = tuple(e.invars[0].aval.shape)
+        if stack not in kv:
             continue
-        update = e.invars[1 if name == "dynamic_update_slice" else 2].aval
-        assert update.size <= SLOTS * KH * hd, (name, update.shape)
-        if name == "dynamic_update_slice":
-            assert update.shape[0] == 1 and update.shape[2] == 1, update.shape
+        # one row of one slot in every layer of the stack
+        assert name == "dynamic_update_slice", name
+        assert e.invars[1].aval.shape == (stack[0], 1, 1, KH, hd), e.invars[1].aval
         writes += 1
-    # one write of the step's rows per K and per V stack of each site
-    assert writes == 2 * ARCHS[arch][0]
+    # one write a slot into the K and the V stack of each site
+    assert writes == 2 * SLOTS * ARCHS[arch][0]
 
 
 @pytest.mark.parametrize("mode", ["per_slot", "shared"])

@@ -1,5 +1,5 @@
-"""The decode step as it stood before the layer scan carried the KV
-cache: each group scans ``(params, caches)`` as xs, every layer writes
+"""The decode step as it stood before the layer scan read the KV cache
+in place: each group scans ``(params, caches)`` as xs, every layer writes
 its new rows into its own slice and the updated slices come back as ys,
 stacked into new caches. Kept as the reference for bitwise parity
 (``tests/test_decode_cache.py``) and as the xs-scanned step the
